@@ -10,6 +10,7 @@ See DESIGN.md §9.  The legacy kwarg entry points (``repro.core.ficabu``)
 are deprecation shims over this module and remain bit-identical.
 """
 from .facade import (ForgetRequest, Unlearner,  # noqa: F401
-                     compilation_cache_entries, enable_compilation_cache)
+                     compilation_cache_entries, enable_compilation_cache,
+                     resolve_cache_dir)
 from .specs import (MODES, DampenSpec, ExecSpec, HaltSpec,  # noqa: F401
                     QuantSpec, RefreshSpec, ServeSpec, UnlearnSpec)

@@ -43,17 +43,36 @@ class ForgetRequest:
     tag: Optional[Any] = None
 
 
-def enable_compilation_cache(cache_dir: str) -> int:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (created if
-    missing) with thresholds dropped to zero so every program is eligible.
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# the checkout's own cache directory (gitignored): a fixed path, so every
+# process started from this checkout finds what earlier ones compiled
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def resolve_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """Where the persistent compilation cache lives: the environment's
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (no argument overrides
+    it), else ``cache_dir``, else the checkout's ``.jax_cache``."""
+    return os.environ.get(CACHE_ENV) or cache_dir or DEFAULT_CACHE_DIR
+
+
+def enable_compilation_cache(cache_dir: Optional[str] = None) -> int:
+    """Point JAX's persistent compilation cache at ``resolve_cache_dir(
+    cache_dir)`` (created if missing) with thresholds dropped to zero so
+    every program is eligible.
     Returns the number of entries already on disk — a cold process start
     with a warm cache should then add ZERO new entries (the serve.py
     ``--check`` gate asserts exactly that).  Idempotent for the same dir;
     the cache is PROCESS-GLOBAL, so pointing it somewhere else after it was
     configured raises instead of silently repointing every facade's cache
     (per-tenant cache dirs are the ROADMAP multi-tenant item, not this)."""
+    env_dir = os.environ.get(CACHE_ENV)
+    cache_dir = resolve_cache_dir(cache_dir)
     current = jax.config.jax_compilation_cache_dir
-    if current and os.path.abspath(current) != os.path.abspath(cache_dir):
+    if not env_dir and current \
+            and os.path.abspath(current) != os.path.abspath(cache_dir):
         raise ValueError(
             f"the persistent compilation cache already points at {current!r} "
             f"for this process; refusing to repoint it to {cache_dir!r} — "

@@ -352,6 +352,9 @@ class ServeSpec:
                         after the drain fired, making the publication step
                         — and with it the telemetry event stream —
                         deterministic regardless of sweep-thread timing.
+    ``tau``             the forget-accuracy target a drain sweeps down to
+                        (``HaltSpec.tau``); a negative target is never met,
+                        so every layer is swept.
 
     JSON round-trip via ``to_json``/``from_json``; validation raises
     ``ValueError`` with actionable messages, never ``assert`` — the same
@@ -370,6 +373,7 @@ class ServeSpec:
     max_batch: int = 8
     admit_chunk: int = 4
     publish_lag: int = 16
+    tau: float = 0.6
     # pre-publication drain guard (repro.robust.GuardSpec), threaded into
     # the lowered UnlearnSpec's ExecSpec — see ``FleetSpec.guard`` for the
     # fleet-wide default
@@ -428,6 +432,7 @@ class ServeSpec:
                  f"ServeSpec.publish_lag must be an int >= 1 step "
                  f"(publication is always between decode steps), "
                  f"got {self.publish_lag!r}")
+        _finite(self.tau, "ServeSpec.tau")
         if isinstance(self.guard, dict):
             object.__setattr__(self, "guard", GuardSpec.from_dict(self.guard))
         _require(self.guard is None or isinstance(self.guard, GuardSpec),
@@ -438,13 +443,13 @@ class ServeSpec:
     def to_unlearn_spec(self) -> "UnlearnSpec":
         """Lower to the deployment's engine-facing ``UnlearnSpec`` — the
         exact mapping the legacy ``serve.default_serve_spec`` hardcoded
-        (alpha/tau/checkpoint cadence pinned for the serving smoke lane;
+        (alpha and checkpoint cadence pinned for the serving smoke lane;
         ``refresh_every > 0`` arms a 2-microbatch, decay-0.5 EMA refresh)."""
         refresh = (RefreshSpec(every_drains=self.refresh_every,
                                max_batches=2, decay=0.5)
                    if self.refresh_every > 0 else None)
         return UnlearnSpec.for_mode(
-            "ficabu", alpha=8.0, lam=1.0, tau=0.6, checkpoint_every=2,
+            "ficabu", alpha=8.0, lam=1.0, tau=self.tau, checkpoint_every=2,
             chunk_size=self.chunk_size, cache_dir=self.cache_dir,
             sweep_mode=self.sweep_mode, precision=self.precision,
             guard=self.guard, refresh=refresh)

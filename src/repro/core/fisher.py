@@ -102,12 +102,18 @@ def fisher_tree(loss_fn: Callable[[Params, Any], jax.Array], params: Params,
 
     def mean_sq_over(chunks_batch, cs):
         chunks = chunked(chunks_batch, cs)
+        n_chunks = _batch_len(chunks)
 
-        def per_chunk(c):
-            return _square_tree(jax.grad(loss_fn)(params, c))
+        def per_chunk(acc, c):
+            sq = _square_tree(jax.grad(loss_fn)(params, c))
+            return _add_trees(acc, sq), None
 
-        sq = jax.lax.map(per_chunk, chunks)  # sequential: O(1) extra memory
-        return jax.tree_util.tree_map(lambda x: jnp.mean(x, axis=0), sq)
+        # sequential sum in a scan carry: one f32 Fisher tree of extra
+        # memory, where a stacked map would hold one per chunk
+        zero = jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, F32),
+                                      params)
+        total, _ = jax.lax.scan(per_chunk, zero, chunks)
+        return jax.tree_util.tree_map(lambda x: x / n_chunks, total)
 
     if head == n:
         return mean_sq_over(batch, chunk_size)

@@ -98,11 +98,17 @@ class LMDataConfig:
     seed: int = 0
 
 
+# each domain's transition matrix is dense [span, span]; the cap keeps a real
+# vocabulary (262k tokens) at O(cap^2) host memory instead of ~34 GB
+MAX_DOMAIN_SPAN = 2048
+
+
 def make_lm_domains(cfg: LMDataConfig) -> Tuple[np.ndarray, np.ndarray]:
     """Returns (tokens [N, seq_len+1], domain_ids [N]). Each domain is a
     first-order Markov chain concentrated on its own token sub-range."""
     rng = np.random.default_rng(cfg.seed)
-    span = max(8, int(cfg.vocab * cfg.domain_vocab_frac))
+    span = min(max(8, int(cfg.vocab * cfg.domain_vocab_frac)),
+               MAX_DOMAIN_SPAN)
     seqs, doms = [], []
     for d in range(cfg.n_domains):
         lo = (d * span // 2) % max(1, cfg.vocab - span)

@@ -10,10 +10,12 @@ the entire sweep lowers as ONE jitted program:
 
   * the forget-batch forward (activation collection) and the logit
     cotangents run inside the program — no separate dispatch;
-  * per-layer params, global Fisher and S(l)-scaled ``(alpha, lam)`` scalars
-    are stacked into leading-``[L_sweep, ...]`` arrays and the back-to-front
-    walk (vjp + Fisher square-accumulate + dampen, cotangent threading
-    between layers) is a single ``lax.scan``;
+  * the carried edit state is stacked into leading-``[L_sweep, ...]``
+    arrays, and the back-to-front walk (vjp + Fisher square-accumulate +
+    dampen, cotangent threading between layers) is a ``lax.scan`` whose xs
+    are the S(l)-scaled ``(alpha, lam)`` rows plus the reference layers and
+    global Fisher of that scan's layers — stacked per scan, so at published
+    widths only one segment's copies are live on the device;
   * layer KINDS may differ (gemma3's local/global pattern) as long as
     shapes agree: the walk runs one scan per CONTIGUOUS same-kind segment,
     each body applying one representative apply-closure per kind — sound by
@@ -219,8 +221,8 @@ def build_sweep_program(adapter: ModelAdapter, plan: SweepPlan, *,
         else:
             runs.append((t, sidx, sidx + 1))
 
-    def _stack(tree):
-        subs = [adapter.get_layer(tree, j) for j in range(1, L - 1)]
+    def _stack(tree, depths=range(1, L - 1)):
+        subs = [adapter.get_layer(tree, j) for j in depths]
         return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *subs)
 
     def _constrain_stack(tree):
@@ -323,9 +325,10 @@ def build_sweep_program(adapter: ModelAdapter, plan: SweepPlan, *,
         acts_mid = jnp.stack([jnp.stack(r[:Lb]) for r in acts_rows])
         acts_head = jnp.stack([r[Lb] for r in acts_rows])
 
-        ref_stack = _constrain_stack(_stack(ref_run))
+        # only the carried edit state is stacked whole (checkpoints walk the
+        # edited suffix); the vjp reference and the global Fisher are
+        # stacked per segment below and stream in as that scan's xs
         edit_stack = _constrain_stack(_stack(edit_tree))
-        fish_stack = _constrain_stack(_stack(fisher))
         if int8:
             # the carried edit state: stacked int8 codes + stacked f32
             # per-(layer, channel) scale tables — lead_axes=2 over the
@@ -395,11 +398,7 @@ def build_sweep_program(adapter: ModelAdapter, plan: SweepPlan, *,
         def make_body(apply_fn):
             def body(carry, xs):
                 stack_cur, cot_c, act, st = carry
-                bidx, sc, is_cp, l_now = xs
-                ref_layer = jax.tree_util.tree_map(
-                    lambda x: x[bidx], ref_stack)
-                fish_g = jax.tree_util.tree_map(
-                    lambda x: x[bidx], fish_stack)
+                bidx, sc, is_cp, l_now, ref_layer, fish_g = xs
                 a_c = acts_mid[:, bidx]
 
                 def mid_grads(a_one, c_one):
@@ -436,9 +435,16 @@ def build_sweep_program(adapter: ModelAdapter, plan: SweepPlan, *,
             bidx_arr = jnp.asarray([L - l - 1 for l in seg_ls], I32)
             iscp_arr = jnp.asarray([l in cps_set for l in seg_ls], bool)
             sc_arr = scalars[seg_ls[0] - 1:seg_ls[-1]]
+            depths = [L - l for l in seg_ls]
+            # the barrier orders this segment's stacking after the previous
+            # segment's scan, so only one segment's copies are live at once
+            ref_b, fish_b, carry = jax.lax.optimization_barrier(
+                (ref_run, fisher, carry))
             carry, (ns, af) = jax.lax.scan(
                 make_body(branches[t]), carry,
-                (bidx_arr, sc_arr, iscp_arr, jnp.asarray(seg_ls, I32)))
+                (bidx_arr, sc_arr, iscp_arr, jnp.asarray(seg_ls, I32),
+                 _constrain_stack(_stack(ref_b, depths)),
+                 _constrain_stack(_stack(fish_b, depths))))
             n_sel_rows.extend(ns[i] for i in range(len(seg_ls)))
             acc_rows.extend(af[i] for i in range(len(seg_ls)))
         stack_out, cot, active, stop_l = carry

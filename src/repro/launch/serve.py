@@ -3,6 +3,10 @@
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --smoke \
         --requests 8 --gen-len 16 --forget-domains 1,2
 
+``--smoke`` (the default) serves the registry's reduced config; ``--full``
+serves the published widths and depth (gemma3-1b: 26 layers, d_model 1152,
+vocab 262144, bf16) — sized for an accelerator, see ``chip_smoke.py``.
+
 Serving loop: batched requests -> chunked prefill (``repro.models.lm.prefill``
 consumes the prompt in blocks against the decode caches) -> iterative decode
 with KV caches / recurrent states.  Forget requests can arrive at ANY point;
@@ -30,11 +34,14 @@ domains within a burst share a due batch and coalesce into one sweep.
 non-zero if any drain ran more sweeps than coalesced groups or any drain
 after the first recompiled.
 
-``--cache-dir`` points JAX's persistent compilation cache at a directory
-(``ExecSpec.cache_dir``): a COLD server start with a warm disk cache then
+JAX's persistent compilation cache is always on: it lives where
+``JAX_COMPILATION_CACHE_DIR`` says when that is set (nothing overrides
+it), else in ``--cache-dir`` (``ExecSpec.cache_dir``), else in the
+checkout's ``.jax_cache``.  A COLD server start with a warm disk cache then
 replays every compiled program — prefill, decode, and the engine's fused
-steps — from disk.  With ``--check``, a warm-disk cold start that writes
-any new cache entry (i.e. recompiled anything) fails the gate.
+steps — from disk.  With ``--check`` and a cache placed by the environment
+or ``--cache-dir``, a warm-disk cold start that writes any new cache entry
+(i.e. recompiled anything) fails the gate.
 
 ``--sweep-mode scanned`` (the default) serves every drain through the
 whole-sweep megaprogram (``repro.engine.sweep``): the full back-end-first
@@ -75,6 +82,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 import warnings
 from collections import deque
@@ -86,7 +94,9 @@ import numpy as np
 
 from repro import configs
 from repro.api import (ServeSpec, UnlearnSpec, Unlearner,
-                       compilation_cache_entries, enable_compilation_cache)
+                       compilation_cache_entries, enable_compilation_cache,
+                       resolve_cache_dir)
+from repro.api.facade import CACHE_ENV
 from repro.data import LMDataConfig, make_lm_domains
 from repro.fleet import Fleet, FleetSpec, TenantSpec
 from repro.models import lm as LM
@@ -141,7 +151,8 @@ def _serve_spec_from_unlearn(spec: UnlearnSpec) -> ServeSpec:
                        if spec.refresh is not None else 0),
         sweep_mode=spec.exec.sweep_mode,
         precision=spec.exec.precision,
-        cache_dir=spec.exec.cache_dir)
+        cache_dir=spec.exec.cache_dir,
+        tau=spec.halt.tau)
 
 
 class ForgetService:
@@ -233,6 +244,10 @@ class ForgetService:
     @property
     def refresh_log(self) -> List[Dict]:
         return self._rt.refresh_log
+
+    @property
+    def abort_log(self) -> List[Dict]:
+        return self._rt.abort_log
 
     @property
     def sweeps(self) -> int:
@@ -619,7 +634,7 @@ def _build_lm_tenant(tspec: TenantSpec, args) -> Dict:
             f"{tspec.name!r} declares arch {tspec.arch!r}, a "
             f"{arch.kind!r} architecture — pick LM entries from "
             f"repro.configs")
-    cfg = arch.smoke if args.smoke else arch.full
+    cfg = arch.full if args.full else arch.smoke
     params = LM.init_lm(jax.random.PRNGKey(tspec.seed), cfg)
     dcfg = LMDataConfig(vocab=cfg.vocab, n_domains=4,
                         seq_len=args.prompt_len + args.gen_len,
@@ -690,10 +705,38 @@ def _shared_family_tenant(fleet: Fleet, fspec: FleetSpec) -> Optional[str]:
     return None
 
 
+def _open_cache(cache_dir: Optional[str]) -> Dict:
+    """Enable the persistent compilation cache before the first compile:
+    the environment's ``JAX_COMPILATION_CACHE_DIR``, else ``cache_dir``,
+    else the checkout's ``.jax_cache``.  The cold-start gate is armed only
+    where the location was chosen on purpose — the checkout's default is
+    shared by every command run from it."""
+    path = resolve_cache_dir(cache_dir)
+    return {"dir": path, "entries_before": enable_compilation_cache(path),
+            "gated": bool(cache_dir or os.environ.get(CACHE_ENV))}
+
+
+def _close_cache(cache_info: Dict) -> Dict:
+    return dict(cache_info, entries_new=(
+        compilation_cache_entries(cache_info["dir"])
+        - cache_info["entries_before"]))
+
+
+def _cold_start_problem(cache_info: Dict) -> Optional[str]:
+    """The cold-start gate: a process start against a WARM disk cache must
+    replay every program (prefill, decode, fused steps) from disk — any new
+    cache entry is a recompile the persistence layer missed."""
+    if cache_info["gated"] and cache_info["entries_before"] > 0 \
+            and cache_info["entries_new"] > 0:
+        return (f"cold start with a warm compilation cache "
+                f"({cache_info['entries_before']} entries) still compiled "
+                f"{cache_info['entries_new']} new program(s)")
+    return None
+
+
 def _main_fleet(args) -> dict:
     fspec = FleetSpec.from_file(args.fleet)
-    cache_dir = fspec.serve.cache_dir or args.cache_dir
-    cache_entries0 = enable_compilation_cache(cache_dir) if cache_dir else 0
+    cache_info = _open_cache(fspec.serve.cache_dir or args.cache_dir)
 
     fleet = Fleet.from_spec(fspec, lambda t: _build_lm_tenant(t, args))
 
@@ -740,12 +783,7 @@ def _main_fleet(args) -> dict:
     while fleet.scheduler.pending():
         fleet.drain(float("inf"))
 
-    cache_info = None
-    if cache_dir:
-        cache_info = {"dir": cache_dir,
-                      "entries_before": cache_entries0,
-                      "entries_new": (compilation_cache_entries(cache_dir)
-                                      - cache_entries0)}
+    cache_info = _close_cache(cache_info)
     result = {
         "fleet": fspec.to_dict(),
         "served": served,
@@ -885,12 +923,9 @@ def _main_fleet(args) -> dict:
                     "fleet drains differs bitwise from a solo replay — "
                     "tenant isolation broken")
         # cold-start gate (process-global cache, same as single-tenant)
-        if cache_info and cache_info["entries_before"] > 0 \
-                and cache_info["entries_new"] > 0:
-            problems.append(
-                f"cold start with a warm compilation cache "
-                f"({cache_info['entries_before']} entries) still compiled "
-                f"{cache_info['entries_new']} new program(s)")
+        cold = _cold_start_problem(cache_info)
+        if cold:
+            problems.append(cold)
         if problems:
             _t.log("serve", "FLEET CHECK FAILED: " + "; ".join(problems))
             raise SystemExit(1)
@@ -913,7 +948,24 @@ def _percentile(sorted_vals: List[float], q: float) -> float:
     return sorted_vals[i]
 
 
-def _main_stream(args, cfg, params, tokens, domains, seq_len: int) -> dict:
+def _weights_report(initial, served) -> Dict:
+    """Where the served weights ended up: whether any leaf differs from the
+    initial tree, whether every float leaf is finite, and the platforms of
+    the devices holding them.  Reductions run on the device; the host reads
+    one flag per leaf."""
+    before = jax.tree_util.tree_leaves(initial)
+    after = jax.tree_util.tree_leaves(served)
+    return {
+        "changed": any(bool(jnp.any(a != b)) for a, b in zip(before, after)),
+        "finite": all(bool(jnp.all(jnp.isfinite(b))) for b in after
+                      if jnp.issubdtype(b.dtype, jnp.floating)),
+        "platforms": sorted({d.platform for b in after
+                             for d in b.devices()}),
+    }
+
+
+def _main_stream(args, cfg, params, tokens, domains, seq_len: int,
+                 cache_info: Dict) -> dict:
     """--serve-mode stream: the continuous-batching engine with shadow
     drains and step-deadline publication (DESIGN.md §15)."""
     serve = ServeSpec(cache_dir=args.cache_dir,
@@ -923,7 +975,8 @@ def _main_stream(args, cfg, params, tokens, domains, seq_len: int) -> dict:
                       publish="step",
                       max_batch=args.max_batch,
                       admit_chunk=args.admit_chunk,
-                      publish_lag=args.publish_lag)
+                      publish_lag=args.publish_lag,
+                      tau=args.tau)
     svc = ForgetService(cfg, tokens, domains, seq_len, serve=serve)
     eng = StreamEngine(params, cfg, gen_len=args.gen_len,
                        prompt_len=args.prompt_len,
@@ -954,8 +1007,12 @@ def _main_stream(args, cfg, params, tokens, domains, seq_len: int) -> dict:
         "elapsed_s": round(time.time() - t0, 3),
         "publications": eng.publications,
         "drain_aborts": eng.aborts,
+        "drain_abort_log": [{"guard": a.get("guard"),
+                             "detail": str(a.get("detail"))}
+                            for a in svc.abort_log],
         "dead_letters": svc.scheduler.dead(),
         "params_version": svc.params_version,
+        "weights": _weights_report(params, svc.params),
         "decode_step_p50_ms": round(_percentile(lat, 0.50) * 1e3, 4),
         "decode_step_p99_ms": round(_percentile(lat, 0.99) * 1e3, 4),
         "decode_compile_signatures": eng.decode_cache_size(),
@@ -967,6 +1024,7 @@ def _main_stream(args, cfg, params, tokens, domains, seq_len: int) -> dict:
                          if svc.unlearner is not None else {}),
         "unlearn_spec": svc.spec.to_dict(),
         "serve_spec": serve.to_dict(),
+        "compilation_cache": _close_cache(cache_info),
     }
     _t.log("serve", f"stream done: {json.dumps(result)}")
     if args.out:
@@ -999,6 +1057,11 @@ def _main_stream(args, cfg, params, tokens, domains, seq_len: int) -> dict:
             problems.append(
                 f"{svc.scheduler.dead()} forget request(s) dead-lettered "
                 "— no request may terminally fail in a fault-free serve")
+        if not result["weights"]["finite"]:
+            problems.append("the served weights hold non-finite values")
+        cold = _cold_start_problem(result["compilation_cache"])
+        if cold:
+            problems.append(cold)
         if problems:
             _t.log("serve", "STREAM CHECK FAILED: " + "; ".join(problems))
             raise SystemExit(1)
@@ -1025,7 +1088,13 @@ def _parse_bursts(args) -> List[List[int]]:
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    size = ap.add_mutually_exclusive_group()
+    size.add_argument("--full", dest="full", action="store_true",
+                      help="serve the registry's full config (published "
+                           "widths and depth)")
+    size.add_argument("--smoke", dest="full", action="store_false",
+                      help="serve the registry's reduced smoke config "
+                           "(the default)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen-len", type=int, default=8)
@@ -1058,6 +1127,10 @@ def main(argv=None) -> dict:
                          "on consecutive batches; '1,2;3' = bursts (comma "
                          "within a burst, ';' between) — a burst coalesces "
                          "into one sweep (overrides --forget-domain)")
+    ap.add_argument("--tau", type=float, default=ServeSpec.tau,
+                    help="forget-accuracy target each drain sweeps down to "
+                         "(ServeSpec.tau); a negative target sweeps every "
+                         "layer")
     ap.add_argument("--coalesce", action="store_true",
                     help="fold a comma list into a single same-due burst")
     ap.add_argument("--check", action="store_true",
@@ -1068,7 +1141,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--cache-dir", default=None,
                     help="persistent XLA compilation cache directory "
                          "(ExecSpec.cache_dir): cold restarts replay "
-                         "compiled programs from disk")
+                         "compiled programs from disk; "
+                         "JAX_COMPILATION_CACHE_DIR, when set, wins, and "
+                         "the default is the checkout's .jax_cache")
     ap.add_argument("--fisher-refresh", type=int, default=0,
                     help="refresh the global Fisher I_D every N drains "
                          "(streamed EMA over retain microbatches at the "
@@ -1098,18 +1173,16 @@ def main(argv=None) -> dict:
     if args.fleet:
         return _main_fleet(args)
 
-    # the cache must be live BEFORE the first compile (prefill/decode too,
-    # not just the engine) for a cold start to be replayable from disk
-    cache_entries0 = (enable_compilation_cache(args.cache_dir)
-                      if args.cache_dir else 0)
-
     spec = configs.get(args.arch)
     if spec.kind != "lm":
         raise ValueError(
             f"serve.py drives an LM decode loop; --arch {args.arch!r} is a "
             f"{spec.kind!r} architecture — pick an LM entry from "
             f"repro.configs")
-    cfg = spec.smoke if args.smoke else spec.full
+    # the cache must be live BEFORE the first compile (prefill/decode too,
+    # not just the engine) for a cold start to be replayable from disk
+    cache_info = _open_cache(args.cache_dir)
+    cfg = spec.full if args.full else spec.smoke
     key = jax.random.PRNGKey(0)
     params = LM.init_lm(key, cfg)
 
@@ -1120,7 +1193,7 @@ def main(argv=None) -> dict:
 
     if args.serve_mode == "stream":
         return _main_stream(args, cfg, params, tokens, domains,
-                            dcfg.seq_len)
+                            dcfg.seq_len, cache_info)
 
     decode_jit = jax.jit(
         lambda p, c, t, pos: LM.decode_step(p, cfg, t, c, pos))
@@ -1130,7 +1203,8 @@ def main(argv=None) -> dict:
                             cache_dir=args.cache_dir,
                             refresh_every=args.fisher_refresh,
                             sweep_mode=args.sweep_mode,
-                            precision=args.precision))
+                            precision=args.precision,
+                            tau=args.tau))
     if args.unlearn_after >= 0:
         for i, burst in enumerate(_parse_bursts(args)):
             for d in burst:
@@ -1155,12 +1229,7 @@ def main(argv=None) -> dict:
 
     done = [r for r in svc.log if "engine" in r]
     last = done[-1] if done else {}
-    cache_info = None
-    if args.cache_dir:
-        cache_info = {"dir": args.cache_dir,
-                      "entries_before": cache_entries0,
-                      "entries_new": (compilation_cache_entries(args.cache_dir)
-                                      - cache_entries0)}
+    cache_info = _close_cache(cache_info)
     refresh_info = None
     if args.fisher_refresh > 0:
         refresh_info = {"every_drains": args.fisher_refresh,
@@ -1239,15 +1308,9 @@ def main(argv=None) -> dict:
             problems.append(
                 "precision='int8' with the scanned megaprogram never "
                 "launched an int8_sweep program (int8 family unused)")
-        # cold-start gate: a process start against a WARM disk cache must
-        # replay every program (prefill, decode, fused steps) from disk —
-        # any new cache entry is a recompile the persistence layer missed
-        if cache_info and cache_info["entries_before"] > 0 \
-                and cache_info["entries_new"] > 0:
-            problems.append(
-                f"cold start with a warm compilation cache "
-                f"({cache_info['entries_before']} entries) still compiled "
-                f"{cache_info['entries_new']} new program(s)")
+        cold = _cold_start_problem(cache_info)
+        if cold:
+            problems.append(cold)
         # streamed-refresh gates: the refresh ran between drains, every
         # refresh after the first replayed the cached program (zero
         # compiles), and the refreshed I_D beats the stale snapshot against

@@ -49,7 +49,13 @@ def build(arch_id: str, smoke: bool, seq: int, vocab_cap: Optional[int] = None):
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    size = ap.add_mutually_exclusive_group()
+    size.add_argument("--full", dest="full", action="store_true",
+                      help="train the registry's full config (published "
+                           "widths and depth)")
+    size.add_argument("--smoke", dest="full", action="store_false",
+                      help="train the registry's reduced smoke config "
+                           "(the default)")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=32)
@@ -64,7 +70,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--forget-domain", type=int, default=2)
     args = ap.parse_args(argv)
 
-    cfg = build(args.arch, args.smoke, args.seq)
+    cfg = build(args.arch, not args.full, args.seq)
     key = jax.random.PRNGKey(0)
 
     dcfg = LMDataConfig(vocab=cfg.vocab, n_domains=8, seq_len=args.seq,

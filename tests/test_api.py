@@ -10,6 +10,7 @@
   * facade error paths reject with ValueError;
   * the api-gate script (CI boundary check) passes on the tree.
 """
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -261,7 +262,11 @@ def test_enable_compilation_cache_conflicting_dir_rejected(tmp_path):
     import jax as _jax
     from repro.api import enable_compilation_cache
     current = _jax.config.jax_compilation_cache_dir
-    if current:
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # the environment places the cache: no argument repoints it
+        enable_compilation_cache(str(tmp_path / "other-cache"))
+        assert _jax.config.jax_compilation_cache_dir == current
+    elif current:
         other = str(tmp_path / "other-cache")
         with pytest.raises(ValueError, match="process-global"):
             enable_compilation_cache(other)
@@ -276,6 +281,30 @@ def test_enable_compilation_cache_conflicting_dir_rejected(tmp_path):
             enable_compilation_cache(a)  # idempotent for the same dir
         finally:
             _jax.config.update("jax_compilation_cache_dir", None)
+
+
+def test_environment_cache_dir_wins(tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set, the persistent cache lives
+    there: neither an argument nor ExecSpec.cache_dir places another."""
+    import jax as _jax
+    from repro.api import enable_compilation_cache, resolve_cache_dir
+    from repro.api.facade import DEFAULT_CACHE_DIR
+    before = _jax.config.jax_compilation_cache_dir
+    env_dir = str(tmp_path / "env-cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        assert resolve_cache_dir(str(tmp_path / "arg")) == env_dir
+        assert enable_compilation_cache(str(tmp_path / "arg")) == 0
+        assert _jax.config.jax_compilation_cache_dir == env_dir
+        assert os.path.isdir(env_dir)
+        assert not (tmp_path / "arg").exists()
+    finally:
+        _jax.config.update("jax_compilation_cache_dir", before)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert resolve_cache_dir("mine") == "mine"
+    assert resolve_cache_dir() == DEFAULT_CACHE_DIR
+    assert DEFAULT_CACHE_DIR == str(Path(__file__).resolve().parent.parent
+                                    / ".jax_cache")
 
 
 def test_auto_midpoint_actionable_error():

@@ -135,10 +135,12 @@ def test_fleet_spec_cache_dir_conflict():
 def test_serve_spec_round_trip_and_validation():
     s = ServeSpec(chunk_size=2, coalesce=False, refresh_every=3,
                   sweep_mode="layerwise", precision="int8",
-                  cache_dir="/tmp/c", max_forget_samples=4)
+                  cache_dir="/tmp/c", max_forget_samples=4, tau=-1.0)
     assert ServeSpec.from_json(s.to_json()) == s
     low = s.to_unlearn_spec()
     assert low.exec.chunk_size == 2 and low.exec.precision == "int8"
+    assert low.halt.tau == -1.0
+    assert ServeSpec().to_unlearn_spec().halt.tau == 0.6
     assert low.refresh is not None and low.refresh.every_drains == 3
     assert ServeSpec().to_unlearn_spec().refresh is None
     with pytest.raises(ValueError, match="chunk_size"):
@@ -149,6 +151,8 @@ def test_serve_spec_round_trip_and_validation():
         ServeSpec(precision="fp8")
     with pytest.raises(ValueError, match="max_forget_samples"):
         ServeSpec(max_forget_samples=0)
+    with pytest.raises(ValueError, match="tau"):
+        ServeSpec(tau=float("nan"))
 
 
 # ---------------------------------------------------------------------------
