@@ -72,7 +72,9 @@ class UnlearnSession:
         self.programs = programs if programs is not None else ProgramCache()
         self.programs.sessions += 1
         self._ns: Hashable = (adapter.name, adapter.n_layers, donate)
-        self.stats: Dict[str, int] = {
+        # counts, and sweep_wait_s: seconds blocked reading the scanned
+        # sweep's outputs (the drain.wait span), on telemetry.monotonic
+        self.stats: Dict[str, float] = {
             "requests": 0, "group_sweeps": 0,
             "fused_compiles": 0, "fused_hits": 0,
             "partial_compiles": 0, "partial_hits": 0,
@@ -84,6 +86,7 @@ class UnlearnSession:
             "int8_sweep_compiles": 0, "int8_sweep_hits": 0,
             "int8_sweep_launches": 0,
             "quant_compiles": 0, "quant_hits": 0,
+            "sweep_wait_s": 0.0,
         }
 
     # -- program cache ------------------------------------------------------
@@ -315,85 +318,95 @@ class UnlearnSession:
         if plan is None:
             return None
 
-        L = adapter.n_layers
-        cps = (tuple(checkpoint_set(L, cfg.checkpoint_every))
-               if 0 < cfg.checkpoint_every <= L else ())
-        limit = min(L, cfg.max_layers or L)
-        S = (sigmoid_profile(L, cfg.b_r, cfg.c_m) if cfg.balanced
-             else np.ones(L))
-        # the same host arithmetic as the layerwise loop: python-float
-        # product cast to f32, one (alpha, lam) row per paper layer
-        scal = np.empty((limit, 2), np.float32)
-        for l in range(1, limit + 1):
-            s = float(S[l - 1])
-            scal[l - 1, 0] = cfg.alpha * s
-            scal[l - 1, 1] = cfg.lam * s
+        with _t.span("drain.prepare"):
+            L = adapter.n_layers
+            cps = (tuple(checkpoint_set(L, cfg.checkpoint_every))
+                   if 0 < cfg.checkpoint_every <= L else ())
+            limit = min(L, cfg.max_layers or L)
+            S = (sigmoid_profile(L, cfg.b_r, cfg.c_m) if cfg.balanced
+                 else np.ones(L))
+            # the same host arithmetic as the layerwise loop: python-float
+            # product cast to f32, one (alpha, lam) row per paper layer
+            scal = np.empty((limit, 2), np.float32)
+            for l in range(1, limit + 1):
+                s = float(S[l - 1])
+                scal[l - 1, 0] = cfg.alpha * s
+                scal[l - 1, 1] = cfg.lam * s
 
-        int8 = cfg.precision == "int8"
-        family = "int8_sweep" if int8 else "sweep"
-        key = sweep_cache_key(
-            plan, adapter, n_sets=K, params=params,
-            fisher=self.fisher_global, sets=forget_sets, cps=cps,
-            limit=limit, chunk_size=cfg.chunk_size,
-            use_kernel=cfg.use_kernel, precision=cfg.precision,
-            quant_min_scale=cfg.quant_min_scale
-        ) + (self.mesh, self.mesh_sharding)
-        prog = self.sweep_program(key, lambda: build_sweep_program(
-            adapter, plan, n_sets=K, cps=cps, limit=limit,
-            chunk_size=cfg.chunk_size, use_kernel=cfg.use_kernel,
-            mesh=self.mesh, mesh_sharding=self.mesh_sharding,
-            precision=cfg.precision, quant_min_scale=cfg.quant_min_scale,
-            tag=f"sweep{'8' if int8 else ''}:K{K}"), family=family)
+            int8 = cfg.precision == "int8"
+            family = "int8_sweep" if int8 else "sweep"
+            key = sweep_cache_key(
+                plan, adapter, n_sets=K, params=params,
+                fisher=self.fisher_global, sets=forget_sets, cps=cps,
+                limit=limit, chunk_size=cfg.chunk_size,
+                use_kernel=cfg.use_kernel, precision=cfg.precision,
+                quant_min_scale=cfg.quant_min_scale
+            ) + (self.mesh, self.mesh_sharding)
+            prog = self.sweep_program(key, lambda: build_sweep_program(
+                adapter, plan, n_sets=K, cps=cps, limit=limit,
+                chunk_size=cfg.chunk_size, use_kernel=cfg.use_kernel,
+                mesh=self.mesh, mesh_sharding=self.mesh_sharding,
+                precision=cfg.precision, quant_min_scale=cfg.quant_min_scale,
+                tag=f"sweep{'8' if int8 else ''}:K{K}"), family=family)
 
-        ref_tree = params if reference is None else reference
-        if int8:
-            # the program's int8 contract: the reference arrives already
-            # fake-quantised, materialised by the cached fakequant program
-            ref_tree = self._fakequant_program(
-                ref_tree, cfg.quant_min_scale)(ref_tree)
+            ref_tree = params if reference is None else reference
+            if int8:
+                # the program's int8 contract: the reference arrives already
+                # fake-quantised, materialised by the cached fakequant program
+                ref_tree = self._fakequant_program(
+                    ref_tree, cfg.quant_min_scale)(ref_tree)
         inputs_k = tuple(s[0] for s in forget_sets)
         labels_k = tuple(s[1] for s in forget_sets)
-        new_params, stop, n_sel, acc = prog(
-            ref_tree, params, self.fisher_global, inputs_k, labels_k,
-            scal, effective_tau32(cfg.tau))
-        self.stats["sweep_launches"] += 1
-        if int8:
-            self.stats["int8_sweep_launches"] += 1
-        # ONE host read for the whole drain — the scan outputs carry every
-        # per-set halting/selection/trace quantity
-        stop = np.asarray(stop)
-        n_sel = np.asarray(n_sel)
-        acc = np.asarray(acc)
+        with _t.span("drain.sweep"):
+            new_params, stop, n_sel, acc = prog(
+                ref_tree, params, self.fisher_global, inputs_k, labels_k,
+                scal, effective_tau32(cfg.tau))
+            self.stats["sweep_launches"] += 1
+            if int8:
+                self.stats["int8_sweep_launches"] += 1
+            # ONE host read for the whole drain — the scan outputs carry
+            # every per-set halting/selection/trace quantity
+            t0 = _t.monotonic()
+            with _t.span("drain.wait"):
+                stop = np.asarray(stop)
+                n_sel = np.asarray(n_sel)
+                acc = np.asarray(acc)
+            self.stats["sweep_wait_s"] += _t.monotonic() - t0
 
-        prm_counts = _layer_param_counts(adapter, ref_tree)
-        stats_k: List[Dict] = []
-        for k in range(K):
-            sl = int(stop[k])
-            hit = [c for c in cps if c <= sl]
-            macs = MacCounter(
-                adapter.layer_fwd_macs, prm_counts,
-                batch=int(jax.tree_util.tree_leaves(labels_k[k])[0].shape[0]))
-            macs.add_forward_all()
-            for l in range(1, sl + 1):
-                j = L - l
-                macs.add_backward_layer(j)
-                macs.add_fisher_layer(j)
-                macs.add_dampen_layer(j)
-            for c in hit:
-                macs.add_partial_inference(L - c, L)
-            st: Dict[str, Any] = {
-                "stopped_at_l": sl,
-                "checkpoints_hit": hit,
-                "selected_per_layer": {l: int(n_sel[k, l - 1])
-                                       for l in range(1, sl + 1)},
-                "forget_acc_trace": [(c, float(acc[k, c - 1])) for c in hit],
-                "profile_S": S.tolist(),
-                "macs": macs.total,
-                "macs_ssd": MacCounter.ssd_total(adapter.layer_fwd_macs,
-                                                 prm_counts, macs.batch),
-            }
-            st["macs_vs_ssd_pct"] = 100.0 * st["macs"] / max(st["macs_ssd"], 1)
-            stats_k.append(st)
+        # per-set halting, selection and MAC accounting
+        with _t.span("drain.finish"):
+            prm_counts = _layer_param_counts(adapter, ref_tree)
+            stats_k: List[Dict] = []
+            for k in range(K):
+                sl = int(stop[k])
+                hit = [c for c in cps if c <= sl]
+                macs = MacCounter(
+                    adapter.layer_fwd_macs, prm_counts,
+                    batch=int(jax.tree_util.tree_leaves(
+                        labels_k[k])[0].shape[0]))
+                macs.add_forward_all()
+                for l in range(1, sl + 1):
+                    j = L - l
+                    macs.add_backward_layer(j)
+                    macs.add_fisher_layer(j)
+                    macs.add_dampen_layer(j)
+                for c in hit:
+                    macs.add_partial_inference(L - c, L)
+                st: Dict[str, Any] = {
+                    "stopped_at_l": sl,
+                    "checkpoints_hit": hit,
+                    "selected_per_layer": {l: int(n_sel[k, l - 1])
+                                           for l in range(1, sl + 1)},
+                    "forget_acc_trace": [(c, float(acc[k, c - 1]))
+                                         for c in hit],
+                    "profile_S": S.tolist(),
+                    "macs": macs.total,
+                    "macs_ssd": MacCounter.ssd_total(adapter.layer_fwd_macs,
+                                                     prm_counts, macs.batch),
+                }
+                st["macs_vs_ssd_pct"] = (100.0 * st["macs"]
+                                         / max(st["macs_ssd"], 1))
+                stats_k.append(st)
         return new_params, stats_k
 
     # -- the drive loop -----------------------------------------------------

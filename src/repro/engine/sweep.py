@@ -60,6 +60,10 @@ from .fused import _note_trace, grad_fisher_chunks, shape_signature
 F32 = jnp.float32
 I32 = jnp.int32
 Params = Any
+# named scope of the halt-checkpoint evaluations (head, in-scan suffix walk,
+# full-tree walk): it reaches each op's HLO ``op_name``, so a device trace
+# can attribute their time
+CHECKPOINT_SCOPE = "checkpoint"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -386,7 +390,8 @@ def build_sweep_program(adapter: ModelAdapter, plan: SweepPlan, *,
                                              x0)
                 return adapter.acc(logits, lbl)
 
-            a_f = _per_set(head_acc, _unchunk(acts_head), labels_s)
+            with jax.named_scope(CHECKPOINT_SCOPE):
+                a_f = _per_set(head_acc, _unchunk(acts_head), labels_s)
             halted = active & (a_f <= tau)
             stop_l = jnp.where(halted, I32(1), stop_l)
             active = active & ~halted
@@ -420,7 +425,8 @@ def build_sweep_program(adapter: ModelAdapter, plan: SweepPlan, *,
                         return _suffix_acc(stack_cur, stack_s, edit_stack,
                                            head_cp, ctx_head_cp, bidx, x0,
                                            lbl)
-                    return _per_set(one, _unchunk(a_c), labels_s)
+                    with jax.named_scope(CHECKPOINT_SCOPE):
+                        return _per_set(one, _unchunk(a_c), labels_s)
 
                 a_f = jax.lax.cond(is_cp, do_cp,
                                    lambda _: nan_row, None)
@@ -507,7 +513,8 @@ def build_sweep_program(adapter: ModelAdapter, plan: SweepPlan, *,
                                             x)
                 return adapter.acc(x, lbl)
 
-            a_f = _per_set(full_acc, jnp.stack(inputs_k), labels_s)
+            with jax.named_scope(CHECKPOINT_SCOPE):
+                a_f = _per_set(full_acc, jnp.stack(inputs_k), labels_s)
             halted = active & (a_f <= tau)
             stop_l = jnp.where(halted, I32(L), stop_l)
             active = active & ~halted

@@ -65,7 +65,11 @@ class TenantRuntime:
     ``run_due`` is the drain body: coalesce the due domains into ONE engine
     sweep over the unioned forget sets and return the edited weights.  The
     facade's session (and with it every compiled program, hosted in the
-    fleet's shared cache) persists across drains.
+    fleet's shared cache) persists across drains.  Its host phases open the
+    profiler spans ``drain.prepare`` and ``drain.finish`` (the session's
+    ``drain.sweep`` lies between them), and ``stats()`` returns the
+    tenant's counters, ``drain_s`` (seconds inside ``run_due``) and the
+    session's ``sweep_wait_s`` among them.
     """
 
     def __init__(self, name: str, cfg, tokens, domains, seq_len: int,
@@ -121,6 +125,7 @@ class TenantRuntime:
         self.refresh_log: List[Dict] = []  # one entry per Fisher refresh
         self.sweeps = 0
         self.groups = 0
+        self.drain_s = 0.0               # worker seconds inside run_due
         self.stale_fisher = None   # host snapshot of the one-shot I_D
         self.retain_batches: List = []
 
@@ -220,6 +225,14 @@ class TenantRuntime:
         return self._wrap_pad(fb, pad), pad
 
     def run_due(self, params, due_domains, batch_idx):
+        """``_run_due``, with its seconds added to ``drain_s``."""
+        t0 = _t.monotonic()
+        try:
+            return self._run_due(params, due_domains, batch_idx)
+        finally:
+            self.drain_s += _t.monotonic() - t0
+
+    def _run_due(self, params, due_domains, batch_idx):
         """Coalesce ``due_domains`` into one sweep at ``batch_idx``;
         returns (params, ran_any).  With ``coalesce=False`` (the sequential
         baseline, ``ServeSpec.coalesce``) each due request drains as its
@@ -242,7 +255,7 @@ class TenantRuntime:
             applied_idx: List[int] = []
             handled_idx: List[int] = []
             for i, dom in enumerate(due_domains):
-                params, ran = self.run_due(params, [dom], batch_idx)
+                params, ran = self._run_due(params, [dom], batch_idx)
                 viol = self.last_violation
                 if viol is not None:
                     # re-base the sub-sweep's indices onto this call's list:
@@ -264,121 +277,140 @@ class TenantRuntime:
                 (applied_idx if ran else handled_idx).append(i)
                 ran_any = ran_any or ran
             return params, ran_any
-        group: List[Dict] = []
-        # audit entries are BUFFERED until the sweep commits: a guard abort
-        # must not leave log traces claiming requests were merged into a
-        # group that never landed
-        audit: List[Dict] = []
-        handled_idx = []
-        seen = set()
-        n_merged = 0
-        for i, dom in enumerate(due_domains):
-            if dom in seen:
-                # same-domain duplicates union trivially, but every submitted
-                # deletion request must leave an audit-log trace
-                audit.append({"domain": dom, "batch": batch_idx,
-                              "merged_into_group": None})
-                n_merged += 1
-                continue
-            fb, pad = self._forget_batch(dom)
-            if fb is None:
-                audit.append({"domain": dom, "batch": batch_idx,
-                              "skipped": "no forget samples"})
-                handled_idx.append(i)
-                _t.log(self.tag, f"forget request for domain {dom} "
-                       "skipped: no samples in that domain")
-                continue
-            if pad:
-                _t.log(self.tag, f"forget batch for domain {dom} padded "
-                       f"by {pad} repeated samples to a multiple of "
-                       f"{self.chunk}")
-            seen.add(dom)
-            group.append({"domain": dom, "fb": fb, "padded": pad})
-        if not group:
-            self.log.extend(audit)
-            return params, False
-        if _faults.fire("worker_exc", self.name):
-            raise RuntimeError(
-                f"injected shadow-sweep worker exception "
-                f"(tenant {self.name}, batch {batch_idx})")
-        # equalize set sizes within the drain (same wrap-repeat policy as
-        # the CHUNK padding): the scanned megaprogram stacks the group's
-        # forget sets, so a small domain must not force the whole drain
-        # onto the layerwise fallback path.  The layerwise driver handles
-        # ragged groups natively — don't perturb its statistics.
-        widest = max(len(g["fb"]) for g in group)
-        if self.spec.exec.sweep_mode == "scanned":
-            for g in group:
-                extra = widest - len(g["fb"])
-                if extra:
-                    g["fb"] = self._wrap_pad(g["fb"], extra)
-                    g["padded"] += extra
-                    _t.log(self.tag, f"forget batch for domain "
-                           f"{g['domain']} padded by {extra} repeated "
-                           f"samples to the drain's widest set ({widest})")
-
-        unl = self._warm(params)
+        with _t.span("drain.prepare"):
+            group: List[Dict] = []
+            # audit entries are BUFFERED until the sweep commits: a guard abort
+            # must not leave log traces claiming requests were merged into a
+            # group that never landed
+            audit: List[Dict] = []
+            handled_idx = []
+            seen = set()
+            n_merged = 0
+            for i, dom in enumerate(due_domains):
+                if dom in seen:
+                    # same-domain duplicates union trivially, but every
+                    # submitted deletion request must leave an audit trace
+                    audit.append({"domain": dom, "batch": batch_idx,
+                                  "merged_into_group": None})
+                    n_merged += 1
+                    continue
+                fb, pad = self._forget_batch(dom)
+                if fb is None:
+                    audit.append({"domain": dom, "batch": batch_idx,
+                                  "skipped": "no forget samples"})
+                    handled_idx.append(i)
+                    _t.log(self.tag, f"forget request for domain {dom} "
+                           "skipped: no samples in that domain")
+                    continue
+                if pad:
+                    _t.log(self.tag, f"forget batch for domain {dom} padded "
+                           f"by {pad} repeated samples to a multiple of "
+                           f"{self.chunk}")
+                seen.add(dom)
+                group.append({"domain": dom, "fb": fb, "padded": pad})
+            if not group:
+                self.log.extend(audit)
+                return params, False
+            if _faults.fire("worker_exc", self.name):
+                raise RuntimeError(
+                    f"injected shadow-sweep worker exception "
+                    f"(tenant {self.name}, batch {batch_idx})")
+            # equalize set sizes within the drain (same wrap-repeat policy as
+            # the CHUNK padding): the scanned megaprogram stacks the group's
+            # forget sets, so a small domain must not force the whole drain
+            # onto the layerwise fallback path.  The layerwise driver handles
+            # ragged groups natively — don't perturb its statistics.
+            widest = max(len(g["fb"]) for g in group)
+            if self.spec.exec.sweep_mode == "scanned":
+                for g in group:
+                    extra = widest - len(g["fb"])
+                    if extra:
+                        g["fb"] = self._wrap_pad(g["fb"], extra)
+                        g["padded"] += extra
+                        _t.log(self.tag, f"forget batch for domain "
+                               f"{g['domain']} padded by {extra} repeated "
+                               f"samples to the drain's widest set ({widest})")
+            unl = self._warm(params)
         t0 = wall_time()
         new_params, stats_k, gstats = unl.forget_group(
             [ForgetRequest(g["fb"][:, :-1], g["fb"][:, 1:], tag=g["domain"])
              for g in group],
             params=params)
         latency = round(wall_time() - t0, 3)
-        viol = self._check_guard(params, new_params)
-        if viol is not None:
-            # discard the candidate tree: the caller's (live) tree is
-            # returned untouched.  Skip entries flush (those requests are
-            # terminally resolved either way); merge traces do not (their
-            # group never landed).
-            self.log.extend(a for a in audit if "skipped" in a)
-            self.aborts += 1
-            self.last_violation = dict(
-                viol, applied_idx=[], handled_idx=list(handled_idx),
-                requeue_idx=[i for i in range(len(due_domains))
-                             if i not in set(handled_idx)])
-            self.abort_log.append(dict(self.last_violation, batch=batch_idx))
-            _t.log(self.tag, f"guard {viol['guard']!r} rejected the "
-                   f"coalesced sweep at batch {batch_idx} — candidate tree "
-                   f"discarded, live weights keep serving")
-            return params, False
-        params = new_params
-        self.sweeps += gstats["sweeps"]
-        self.groups += 1
-        gi = self.groups - 1
-        for a in audit:
-            if "merged_into_group" in a:
-                a["merged_into_group"] = gi
-        self.log.extend(audit)
-        self.group_log.append({
-            "group": gi, "batch": batch_idx,
-            "domains": [g["domain"] for g in group],
-            "requests": len(group) + n_merged,
-            # the drain's program signature: set count + per-set batch.
-            # Compiled programs are keyed by it, so the --check recompile
-            # gate flags warm drains of a SEEN signature only — the first
-            # drain of a new group size/width legitimately compiles.
-            "sweep_sig": [len(group), widest],
-            "sweeps": gstats["sweeps"], "latency_s": latency,
-            "engine": gstats["engine"],
-        })
-        for g, st in zip(group, stats_k):
-            self.log.append({
-                "domain": g["domain"], "batch": batch_idx, "group": gi,
-                "latency_s": latency, "padded": g["padded"],
-                "stopped_at_l": st["stopped_at_l"],
-                "macs_vs_ssd_pct": st["macs_vs_ssd_pct"],
+        with _t.span("drain.finish"):
+            viol = self._check_guard(params, new_params)
+            if viol is not None:
+                # discard the candidate tree: the caller's (live) tree is
+                # returned untouched.  Skip entries flush (those requests are
+                # terminally resolved either way); merge traces do not (their
+                # group never landed).
+                self.log.extend(a for a in audit if "skipped" in a)
+                self.aborts += 1
+                self.last_violation = dict(
+                    viol, applied_idx=[], handled_idx=list(handled_idx),
+                    requeue_idx=[i for i in range(len(due_domains))
+                                 if i not in set(handled_idx)])
+                self.abort_log.append(dict(self.last_violation,
+                                           batch=batch_idx))
+                _t.log(self.tag, f"guard {viol['guard']!r} rejected the "
+                       f"coalesced sweep at batch {batch_idx} — candidate "
+                       f"tree discarded, live weights keep serving")
+                return params, False
+            params = new_params
+            self.sweeps += gstats["sweeps"]
+            self.groups += 1
+            gi = self.groups - 1
+            for a in audit:
+                if "merged_into_group" in a:
+                    a["merged_into_group"] = gi
+            self.log.extend(audit)
+            self.group_log.append({
+                "group": gi, "batch": batch_idx,
+                "domains": [g["domain"] for g in group],
+                "requests": len(group) + n_merged,
+                # the drain's program signature: set count + per-set batch.
+                # Compiled programs are keyed by it, so the --check recompile
+                # gate flags warm drains of a SEEN signature only — the first
+                # drain of a new group size/width legitimately compiles.
+                "sweep_sig": [len(group), widest],
+                "sweeps": gstats["sweeps"], "latency_s": latency,
                 "engine": gstats["engine"],
             })
-        _t.log(self.tag, f"coalesced sweep {gi}: unlearned domains "
-               f"{[g['domain'] for g in group]} in place "
-               f"(sweeps={gstats['sweeps']}, "
-               f"stop_l={[st['stopped_at_l'] for st in stats_k]}, "
-               f"compiles={gstats['engine']['compiles']}, "
-               f"hits={gstats['engine']['cache_hits']})")
-        # streamed I_D refresh between drains: fold retain microbatches at
-        # the freshly edited weights when the RefreshSpec policy says so
-        self.maybe_refresh(params, batch_idx)
-        return params, True
+            for g, st in zip(group, stats_k):
+                self.log.append({
+                    "domain": g["domain"], "batch": batch_idx, "group": gi,
+                    "latency_s": latency, "padded": g["padded"],
+                    "stopped_at_l": st["stopped_at_l"],
+                    "macs_vs_ssd_pct": st["macs_vs_ssd_pct"],
+                    "engine": gstats["engine"],
+                })
+            _t.log(self.tag, f"coalesced sweep {gi}: unlearned domains "
+                   f"{[g['domain'] for g in group]} in place "
+                   f"(sweeps={gstats['sweeps']}, "
+                   f"stop_l={[st['stopped_at_l'] for st in stats_k]}, "
+                   f"compiles={gstats['engine']['compiles']}, "
+                   f"hits={gstats['engine']['cache_hits']})")
+            # streamed I_D refresh between drains: fold retain microbatches at
+            # the freshly edited weights when the RefreshSpec policy says so
+            self.maybe_refresh(params, batch_idx)
+            return params, True
+
+    def stats(self) -> Dict[str, Any]:
+        """The tenant's counters: drain groups and sweeps, requests logged,
+        applied and aborted, refreshes, the WAL's accounting, the engine
+        session's counters, and the worker's seconds inside ``run_due``
+        (``drain_s``) beside those it spent blocked reading the sweep's
+        outputs (``sweep_wait_s``, counted by the session)."""
+        engine = (dict(self.unlearner.stats)
+                  if self.unlearner is not None else {})
+        return {"arch": self.arch, "groups": self.groups,
+                "sweeps": self.sweeps, "requests": len(self.log),
+                "applied": self.applied_requests, "aborts": self.aborts,
+                "refreshes": len(self.refresh_log),
+                "wal": (self.wal.accounting()
+                        if self.wal is not None else None),
+                "engine": engine, "drain_s": self.drain_s,
+                "sweep_wait_s": engine.get("sweep_wait_s", 0.0)}
 
     # -- guarded drains (DESIGN.md §16) --------------------------------------
     def _retain_probe(self, tree) -> float:
@@ -894,18 +926,8 @@ class Fleet:
 
     def stats(self) -> Dict[str, Any]:
         return {
-            "tenants": {
-                name: {"arch": rt.arch, "groups": rt.groups,
-                       "sweeps": rt.sweeps,
-                       "requests": len(rt.log),
-                       "applied": rt.applied_requests,
-                       "aborts": rt.aborts,
-                       "refreshes": len(rt.refresh_log),
-                       "wal": rt.wal.accounting()
-                       if rt.wal is not None else None,
-                       "engine": dict(rt.unlearner.stats)
-                       if rt.unlearner is not None else {}}
-                for name, rt in self.tenants.items()},
+            "tenants": {name: rt.stats()
+                        for name, rt in self.tenants.items()},
             "program_cache": self.programs.stats(),
             "families": {"/".join(map(str, ns)): n
                          for ns, n in self.family_program_counts().items()},

@@ -81,6 +81,7 @@ bit-identical weights and Fisher (tenant isolation).
 from __future__ import annotations
 
 import argparse
+import concurrent.futures
 import json
 import os
 import time
@@ -261,6 +262,10 @@ class ForgetService:
     def stale_fisher(self):
         return self._rt.stale_fisher
 
+    def stats(self) -> Dict:
+        """The tenant runtime's counters (``TenantRuntime.stats``)."""
+        return self._rt.stats()
+
     @property
     def retain_batches(self) -> List:
         return self._rt.retain_batches
@@ -394,6 +399,15 @@ class StreamEngine:
     fast the worker finishes, so two runs of the same scenario publish at
     identical steps with identical content (drain k+1 chains off drain
     k's output via the runtime's shadow chain).
+
+    Each phase also opens a profiler span (``telemetry.span``):
+    ``engine.step`` around the step, with ``engine.publish`` /
+    ``engine.publish_wait`` / ``engine.fire`` / ``engine.admit`` /
+    ``engine.evict`` / ``engine.decode`` inside it, and ``drain`` around
+    each sweep on the worker thread, linked to its ``engine.fire`` and
+    ``engine.publish_wait`` by ``fire_step`` and ``group`` (the group's
+    position among that step's due groups).  ``stats()`` returns the
+    engine's plain counters.
     """
 
     def __init__(self, params, cfg, *, gen_len: int, prompt_len: int,
@@ -431,9 +445,17 @@ class StreamEngine:
         self.step = 0
         self.publications = 0
         self.aborts = 0
-        self.step_wall: List[float] = []   # per-step loop wall seconds
-        # [deadline_step, future, scheduler group] — the group rides along
-        # so a failed sweep can be requeued/dead-lettered at the deadline
+        self.admissions = 0
+        self.admitted_rows = 0
+        self.padded_rows = 0
+        # publication deadlines that found the drain unfinished, and the
+        # seconds the engine thread then spent blocked joining it
+        self.publish_waits = 0
+        self.publish_wait_s = 0.0
+        self.step_wall: List[float] = []   # per-step loop seconds
+        # [deadline_step, future, scheduler group, fire step, position] —
+        # the group rides along so a failed sweep can be requeued/dead-
+        # lettered at the deadline; fire step and position name it in spans
         self._pending_pubs: List[List] = []
         self._executor = None
 
@@ -479,27 +501,36 @@ class StreamEngine:
             chunk = [self.pending.popleft() for _ in range(take)]
             rows, free = free[:take], free[take:]
             width = self.admit_chunk
-            # fixed-width sub-batch: ONE prefill/admit program signature.
-            # Padding rows repeat the last prompt and scatter to row index
-            # B — out of bounds, dropped by the mode="drop" scatters.
-            prompts = np.stack([p for _, p in chunk]
-                               + [chunk[-1][1]] * (width - take))
-            rows_arr = jnp.asarray(rows + [self.B] * (width - take),
-                                   dtype=jnp.int32)
-            sub_cache = LM.init_cache(self.cfg, width, self.S_max)
-            logits, sub_cache = LM.prefill(self.params, self.cfg,
-                                           jnp.asarray(prompts), sub_cache,
-                                           block=self.prefill_block)
-            first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            (self.cache, self.tok, self.pos, self.gidx, self.outbuf) = \
-                self._admit_fn(self.cache, sub_cache, self.tok, self.pos,
-                               self.gidx, self.outbuf, rows_arr, first)
-            for r, (sid, _) in zip(rows, chunk):
+            seqs = [sid for sid, _ in chunk]
+            with _t.span("engine.admit", seqs=seqs, width=width,
+                         padded=width - take):
+                # fixed-width sub-batch: ONE prefill/admit program
+                # signature.  Padding rows repeat the last prompt and
+                # scatter to row index B — out of bounds, dropped by the
+                # mode="drop" scatters.
+                prompts = np.stack([p for _, p in chunk]
+                                   + [chunk[-1][1]] * (width - take))
+                rows_arr = jnp.asarray(rows + [self.B] * (width - take),
+                                       dtype=jnp.int32)
+                sub_cache = LM.init_cache(self.cfg, width, self.S_max)
+                logits, sub_cache = LM.prefill(self.params, self.cfg,
+                                               jnp.asarray(prompts),
+                                               sub_cache,
+                                               block=self.prefill_block)
+                first = jnp.argmax(logits[:, -1:],
+                                   axis=-1).astype(jnp.int32)
+                (self.cache, self.tok, self.pos, self.gidx,
+                 self.outbuf) = self._admit_fn(
+                    self.cache, sub_cache, self.tok, self.pos, self.gidx,
+                    self.outbuf, rows_arr, first)
+            for r, sid in zip(rows, seqs):
                 self.slot_seq[r] = sid
                 self.slot_written[r] = 1
+            self.admissions += 1
+            self.admitted_rows += take
+            self.padded_rows += width - take
             _t.emit("batch.admit", step=self.step, rows=rows,
-                    seqs=[sid for sid, _ in chunk], width=width,
-                    padded=width - take)
+                    seqs=seqs, width=width, padded=width - take)
 
     def _evict_done(self) -> None:
         for r in range(self.B):
@@ -526,16 +557,24 @@ class StreamEngine:
         if nd is None or nd > step:
             return
         if self._executor is None:
-            import concurrent.futures
             self._executor = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1)   # serializes sweeps: drain k+1 after k
-        for g in svc.scheduler.due_groups(step):
-            fut = self._executor.submit(svc.run_shadow_guarded,
-                                        list(g.payloads), step)
-            self._pending_pubs.append([step + self.publish_lag, fut, g])
+        for pos, g in enumerate(svc.scheduler.due_groups(step)):
+            payloads = list(g.payloads)
+            with _t.span("engine.fire", group=pos, payloads=payloads):
+                fut = self._executor.submit(self._drain, payloads, step,
+                                            pos)
+            self._pending_pubs.append([step + self.publish_lag, fut, g,
+                                       step, pos])
             _t.emit("drain.fire", step=step, n_requests=len(g.payloads),
-                    payloads=list(g.payloads),
+                    payloads=payloads,
                     publish_at=step + self.publish_lag)
+
+    def _drain(self, payloads, step, group):
+        """One shadow sweep, on the worker thread."""
+        with _t.span("drain", group=group, fire_step=step,
+                     payloads=payloads):
+            return self.svc.run_shadow_guarded(payloads, step)
 
     def _publish_due(self, step) -> None:
         if not self._pending_pubs:
@@ -544,14 +583,25 @@ class StreamEngine:
         if not due:
             return
         self._pending_pubs = [p for p in self._pending_pubs if p[0] > step]
+        with _t.span("engine.publish"):
+            self._publish(due)
+
+    def _publish(self, due) -> None:
         svc = self.svc
         published = False
-        for _, fut, g in due:
+        for _, fut, g, fire_step, pos in due:
             # joining at the DEADLINE keeps the publication step (and the
             # published content, via the shadow chain) deterministic no
             # matter how thread timing interleaved the sweep itself
             tree = None
             violation = None
+            if not fut.done():
+                t0 = _t.monotonic()
+                with _t.span("engine.publish_wait", group=pos,
+                             fire_step=fire_step):
+                    concurrent.futures.wait([fut])
+                self.publish_waits += 1
+                self.publish_wait_s += _t.monotonic() - t0
             try:
                 tree, ran, violation = fut.result()
             except Exception as e:   # worker died: nothing staged, abort
@@ -577,21 +627,38 @@ class StreamEngine:
 
     # -- the loop ----------------------------------------------------------
     def step_once(self) -> None:
-        t0 = _t.wall_time()
-        self._publish_due(self.step)
-        self._fire_drains(self.step)
-        self._admit_due()
-        self._evict_done()
-        if any(s is not None for s in self.slot_seq):
-            (self.cache, self.tok, self.pos, self.gidx, self.outbuf) = \
-                self._step_fn(self.params, self.cache, self.tok, self.pos,
-                              self.gidx, self.outbuf)
-            for r in range(self.B):
-                if self.slot_seq[r] is not None:
-                    self.slot_written[r] += 1
-            self._evict_done()
+        t0 = _t.monotonic()
+        with _t.span("engine.step", step_num=self.step):
+            self._publish_due(self.step)
+            self._fire_drains(self.step)
+            self._admit_due()
+            with _t.span("engine.evict"):
+                self._evict_done()
+            if any(s is not None for s in self.slot_seq):
+                with _t.span("engine.decode"):
+                    (self.cache, self.tok, self.pos, self.gidx,
+                     self.outbuf) = self._step_fn(
+                        self.params, self.cache, self.tok, self.pos,
+                        self.gidx, self.outbuf)
+                for r in range(self.B):
+                    if self.slot_seq[r] is not None:
+                        self.slot_written[r] += 1
+                with _t.span("engine.evict"):
+                    self._evict_done()
         self.step += 1
-        self.step_wall.append(_t.wall_time() - t0)
+        self.step_wall.append(_t.monotonic() - t0)
+
+    def stats(self) -> Dict[str, float]:
+        """The engine's counters: steps, admissions and their rows (padding
+        rows apart), publications, aborts, and the publication deadlines
+        that blocked on an unfinished drain with the seconds they
+        blocked."""
+        return {"steps": self.step, "admissions": self.admissions,
+                "admitted_rows": self.admitted_rows,
+                "padded_rows": self.padded_rows,
+                "publications": self.publications, "aborts": self.aborts,
+                "publish_waits": self.publish_waits,
+                "publish_wait_s": self.publish_wait_s}
 
     def run(self) -> Dict[int, np.ndarray]:
         """Serve until every enqueued sequence completed, then flush any
@@ -1013,8 +1080,11 @@ def _main_stream(args, cfg, params, tokens, domains, seq_len: int,
         "dead_letters": svc.scheduler.dead(),
         "params_version": svc.params_version,
         "weights": _weights_report(params, svc.params),
-        "decode_step_p50_ms": round(_percentile(lat, 0.50) * 1e3, 4),
-        "decode_step_p99_ms": round(_percentile(lat, 0.99) * 1e3, 4),
+        # host loop seconds of a step (no device sync: not decode time)
+        "step_host_p50_ms": round(_percentile(lat, 0.50) * 1e3, 4),
+        "step_host_p99_ms": round(_percentile(lat, 0.99) * 1e3, 4),
+        "engine_counters": eng.stats(),
+        "drain_counters": svc.stats(),
         "decode_compile_signatures": eng.decode_cache_size(),
         "unlearn_requests": svc.log,
         "group_log": svc.group_log,

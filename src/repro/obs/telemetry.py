@@ -27,7 +27,18 @@ telemetry capture is active.
 packages: ``tools/api_gate.py`` AST-bans ``time.time``/``datetime.now``
 inside ``src/repro/load`` and ``src/repro/fleet``, so every wall-clock
 datum flows through here and lands in a nondeterministic-by-convention
-field instead of leaking into the deterministic stream.
+field instead of leaking into the deterministic stream.  ``monotonic()``
+is the one clock that durations accumulated in plain counters are read
+with (the engine's and the drain worker's ``stats()``); counters never
+enter the event stream.
+
+``span(name, **ids)`` is the program's one host-span helper: a
+``jax.profiler.TraceAnnotation`` named ``ficabu.<name>``, so each span lands
+in the profiler's host plane on the same clock as the device planes.  It
+emits no event and reads no clock of its own: with no profiler active it
+costs one ``TraceMe`` enter/exit, and the determinism fingerprints come out
+identical with and without a profiler.  The profiler keeps spans in memory
+and writes them at ``stop_trace``; there is no second recorder.
 """
 from __future__ import annotations
 
@@ -38,6 +49,8 @@ import threading
 import time as _time
 from typing import Any, Dict, Iterable, List, Optional
 
+from jax import profiler as _profiler
+
 # field names carrying wall-clock-derived values; stripped (recursively) by
 # canonical_events before determinism fingerprints
 NONDETERMINISTIC_KEYS = frozenset({"latency_s", "wall_s", "elapsed_s"})
@@ -47,6 +60,30 @@ def wall_time() -> float:
     """Wall-clock seconds — the sanctioned read for load/fleet code (see
     module docstring); results belong in ``NONDETERMINISTIC_KEYS`` fields."""
     return _time.time()
+
+
+def monotonic() -> float:
+    """Monotonic seconds — the clock of every duration a plain counter
+    accumulates (``StreamEngine.publish_wait_s``, ``TenantRuntime.drain_s``,
+    the session's ``sweep_wait_s``)."""
+    return _time.perf_counter()
+
+
+SPAN_PREFIX = "ficabu."
+_SPAN_NAMES: Dict[str, str] = {}     # name -> prefixed name, built once
+
+
+def span(name: str, **ids: Any) -> _profiler.TraceAnnotation:
+    """Host span ``ficabu.<name>`` for the profiler's trace; ``ids`` become
+    the span's arguments (``step_num`` makes it a step annotation).  Use as
+    a context manager.  Inactive profiler: one ``TraceMe`` enter/exit, no
+    formatting (the arguments are encoded only while a trace is taken)."""
+    full = _SPAN_NAMES.get(name)
+    if full is None:
+        full = _SPAN_NAMES.setdefault(name, SPAN_PREFIX + name)
+    if "step_num" in ids:
+        return _profiler.StepTraceAnnotation(full, **ids)
+    return _profiler.TraceAnnotation(full, **ids)
 
 
 class VirtualClock:
