@@ -117,11 +117,15 @@ class UnlearnConfig:
 
 
 def _layer_param_counts(adapter: ModelAdapter, params: Params) -> List[int]:
-    out = []
-    for j in range(adapter.n_layers):
-        sub = adapter.get_layer(params, j)
-        out.append(sum(x.size for x in jax.tree_util.tree_leaves(sub)))
-    return out
+    """Element count of each paper layer's parameters (the MAC statistics'
+    ``layer_params``), from shapes alone: the layer views are traced
+    abstractly, so no device op runs and ``params`` may be a tree of
+    ``jax.ShapeDtypeStruct``."""
+    layers = jax.eval_shape(
+        lambda p: [adapter.get_layer(p, j) for j in range(adapter.n_layers)],
+        params)
+    return [sum(x.size for x in jax.tree_util.tree_leaves(sub))
+            for sub in layers]
 
 
 def _chunk(x, cs):

@@ -103,12 +103,13 @@ class ProgramCache:
     def __len__(self) -> int:
         return len(self._progs)
 
-    # -- sweep plans (pure structure, no compile counters) ------------------
+    # -- shape-derived memos (pure structure, no compile counters) -----------
     def plan_or_build(self, key: Hashable, builder: Callable[[], Any]) -> Any:
-        """Sweep-plan memo (``plan_scanned_sweep`` results, including the
-        ``None`` = not-scannable verdict): plans are derived by
-        ``jax.eval_shape`` so they carry no compile cost worth counting, but
-        same-family tenants still skip re-deriving them."""
+        """Memo of what the session derives from shapes alone: sweep plans
+        (``plan_scanned_sweep`` results, including the ``None`` =
+        not-scannable verdict) and per-layer parameter counts.  Both come
+        from ``jax.eval_shape`` so they carry no compile cost worth
+        counting here, but same-family tenants still skip re-deriving them."""
         if key not in self._plans:
             self._plans[key] = builder()
         return self._plans[key]

@@ -86,6 +86,9 @@ class UnlearnSession:
             "int8_sweep_compiles": 0, "int8_sweep_hits": 0,
             "int8_sweep_launches": 0,
             "quant_compiles": 0, "quant_hits": 0,
+            # per-layer parameter counts for the MAC statistics, memoised
+            # per tree shape (built once, then hit on every drain)
+            "param_count_builds": 0, "param_count_hits": 0,
             "sweep_wait_s": 0.0,
         }
 
@@ -108,6 +111,23 @@ class UnlearnSession:
         count them); keys are the stream-level keys, namespace stripped."""
         return {k[1:]: v for k, v in self.programs._progs.items()
                 if k[0] == self._ns and len(k) > 1 and k[1] == "refresh"}
+
+    def _param_counts(self, tree: Params) -> Tuple[int, ...]:
+        """Per-paper-layer parameter counts for the MAC statistics, memoised
+        per tree shape beside the sweep plan: they depend on shapes alone,
+        so a drain reads them without a device op, and same-family tenants
+        sharing the program cache count once."""
+        built = []
+
+        def build():
+            built.append(True)
+            return tuple(_layer_param_counts(self.adapter, tree))
+
+        counts = self.programs.plan_or_build(
+            (self._ns, "param_counts", shape_signature(tree)), build)
+        self.stats["param_count_builds" if built
+                   else "param_count_hits"] += 1
+        return counts
 
     def _layer_key(self, j: int) -> Hashable:
         lk = getattr(self.adapter, "layer_key", None)
@@ -375,7 +395,7 @@ class UnlearnSession:
 
         # per-set halting, selection and MAC accounting
         with _t.span("drain.finish"):
-            prm_counts = _layer_param_counts(adapter, ref_tree)
+            prm_counts = self._param_counts(ref_tree)
             stats_k: List[Dict] = []
             for k in range(K):
                 sl = int(stop[k])
@@ -455,7 +475,7 @@ class UnlearnSession:
         S = (sigmoid_profile(L, cfg.b_r, cfg.c_m) if cfg.balanced
              else np.ones(L))
 
-        prm_counts = _layer_param_counts(adapter, params)
+        prm_counts = self._param_counts(params)
         macs = MacCounter(adapter.layer_fwd_macs, prm_counts,
                           batch=int(jax.tree_util.tree_leaves(labels)[0].shape[0]))
 
@@ -658,7 +678,7 @@ class UnlearnSession:
             params = ref_run if reference is None else fqp(params)
         else:
             ref_run = ref_tree
-        prm_counts = _layer_param_counts(adapter, ref_tree)
+        prm_counts = self._param_counts(ref_tree)
         cs = cfg.chunk_size
 
         acts_k: List[List[jax.Array]] = []

@@ -12,6 +12,8 @@
   * program-cache lifecycle: ONE sweep compile, then zero warm retraces
     (TRACE_LOG pin) across repeats, hyperparameter changes, and coalesced
     re-drains;
+  * per-layer parameter counts: from shapes alone (every adapter family),
+    memoised per tree shape in the program cache;
   * the API plumbing: ``ExecSpec.sweep_mode`` validation / JSON round trip
     / ``to_config`` lowering, and ``dist.sharding.stacked_param_pspecs``
     for the stacked [L, ...] trees.
@@ -312,6 +314,101 @@ def test_coalesced_second_drain_zero_retraces(lm_setting, trace_log):
     assert g2["engine"]["cache_hits"] == 1
     assert g2["engine"]["sweep_launches"] == 1
     assert len(trace_log) == 0, f"unexpected retraces: {trace_log}"
+
+
+# ---------------------------------------------------------------------------
+# per-layer parameter counts: from shapes alone, memoised per tree shape
+# ---------------------------------------------------------------------------
+def _eager_param_counts(adapter, params):
+    """The counts as the engine once took them: slice each layer out of the
+    concrete tree on the device and sum the leaf sizes."""
+    return [sum(x.size for x in jax.tree_util.tree_leaves(
+        adapter.get_layer(params, j))) for j in range(adapter.n_layers)]
+
+
+def _family_lm(key):
+    # 5 blocks over a 2-kind pattern: two stacked periods plus a tail block
+    cfg_m = LM.LMConfig(name="t-cnt", n_layers=5, d_model=32, n_heads=4,
+                        n_kv_heads=2, d_ff=64, vocab=64,
+                        block_pattern=("local", "attn"), window=8,
+                        tie_embeddings=True)
+    return adapters.lm_adapter(cfg_m, 16), LM.init_lm(key, cfg_m)
+
+
+def _family_moe_lm(key):
+    cfg_m = LM.LMConfig(name="moe-cnt", n_layers=2, d_model=32, n_heads=4,
+                        n_kv_heads=2, d_ff=64, vocab=64,
+                        moe=LM.MoESpec(num_experts=4, top_k=2))
+    return adapters.lm_adapter(cfg_m, 16), LM.init_lm(key, cfg_m)
+
+
+def _family_resnet(key):
+    cfg_m = V.ResNetConfig(width=8, n_classes=6, img_size=16)
+    return adapters.resnet_adapter(cfg_m), V.init_resnet(key, cfg_m)
+
+
+def _family_vit(key):
+    cfg_m = V.ViTConfig(name="vit-cnt", n_layers=3, d_model=32, n_heads=2,
+                        d_ff=64, n_classes=6, img_size=16, patch=4)
+    return adapters.vit_adapter(cfg_m), V.init_vit(key, cfg_m)
+
+
+def _family_encdec(key):
+    from repro.models import encdec as ED
+    cfg_m = ED.EncDecConfig(name="ed-cnt", n_enc_layers=1, n_dec_layers=2,
+                            d_model=32, n_heads=2, n_kv_heads=2, d_ff=64,
+                            vocab=64, n_frames=8)
+    adapter = adapters.encdec_adapter(cfg_m, 8, jnp.zeros((8, 8, 32)))
+    return adapter, ED.init_encdec(key, cfg_m)
+
+
+@pytest.mark.parametrize("family", [_family_lm, _family_moe_lm,
+                                    _family_resnet, _family_vit,
+                                    _family_encdec],
+                         ids=["lm", "moe_lm", "resnet", "vit", "encdec"])
+def test_layer_param_counts_from_shapes(key, family):
+    """``_layer_param_counts`` on a tree of ShapeDtypeStructs — which
+    cannot dispatch a device op — equals the eager per-layer slicing count
+    on the concrete tree, for every adapter family."""
+    adapter, params = family(key)
+    abstract = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+    counts = cau._layer_param_counts(adapter, abstract)
+    assert counts == _eager_param_counts(adapter, params)
+    assert all(type(c) is int and c > 0 for c in counts)
+    assert cau._layer_param_counts(adapter, params) == counts
+
+
+def test_param_counts_memoised_per_shape(lm_setting):
+    """Two scanned drains on one session count once and hit once; a second
+    session sharing the program cache hits too. The memoised counts give
+    the MAC statistics the legacy oracle computes from scratch."""
+    m = lm_setting
+    toks = m["toks"]
+    fb = toks[:8]
+    cfg = _scanned(cau.UnlearnConfig(alpha=6.0, lam=0.5, tau=-1.0,
+                                     checkpoint_every=2, balanced=True,
+                                     chunk_size=4))
+    sess = UnlearnSession(m["adapter"], m["i_d"])
+    sess.forget_many(m["params"], [(toks[8:16, :-1], toks[8:16, 1:])], cfg)
+    assert (sess.stats["param_count_builds"],
+            sess.stats["param_count_hits"]) == (1, 0)
+    _, st, g = sess.forget_many(m["params"], [(fb[:, :-1], fb[:, 1:])], cfg)
+    assert g["engine"]["sweep_mode"] == "scanned"
+    assert (sess.stats["param_count_builds"],
+            sess.stats["param_count_hits"]) == (1, 1)
+
+    _, s_legacy = cau.context_adaptive_unlearn_legacy(
+        m["adapter"], m["params"], m["i_d"], fb[:, :-1], fb[:, 1:], cfg)
+    for k in ("macs", "macs_ssd", "macs_vs_ssd_pct"):
+        assert st[0][k] == s_legacy[k], (k, st[0][k], s_legacy[k])
+
+    tenant = UnlearnSession(m["adapter"], m["i_d"], programs=sess.programs)
+    _, st_t, _ = tenant.forget_many(m["params"], [(fb[:, :-1], fb[:, 1:])],
+                                    cfg)
+    assert (tenant.stats["param_count_builds"],
+            tenant.stats["param_count_hits"]) == (0, 1)
+    assert st_t[0]["macs"] == s_legacy["macs"]
 
 
 # ---------------------------------------------------------------------------
