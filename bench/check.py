@@ -58,14 +58,15 @@ def changed_bits(live: Dict[str, Any], base: Dict[str, Any]
 def _mismatch(prog_bits: Dict[str, np.ndarray], ref: Dict[str, Any],
               base: Dict[str, Any], counted: Dict[str, np.ndarray]) -> float:
     """Elements edited by exactly one side over those the reference edited,
-    summed over the counted leaf rows."""
+    summed over the counted leaf rows (``counted[k]``: one flag per row of
+    a leaf stacked over blocks, one for any other leaf)."""
     xor_n = ref_n = 0
     for k, a in ref.items():
         diff = a != base[k]
         n = int(np.prod(a.shape))
         prog = jnp.unpackbits(jnp.asarray(prog_bits[k]))[:n].reshape(a.shape)
-        stacked = a.ndim >= 2 and k not in ("embed", "lm_head")
-        axes = tuple(range(1, a.ndim)) if stacked else None
+        # a stack of one block sums to the same one row either way
+        axes = tuple(range(1, a.ndim)) if len(counted[k]) > 1 else None
         x = np.atleast_1d(np.asarray(jnp.sum(diff != prog.astype(bool),
                                              axis=axes)))
         r = np.atleast_1d(np.asarray(jnp.sum(diff, axis=axes)))
@@ -75,8 +76,8 @@ def _mismatch(prog_bits: Dict[str, np.ndarray], ref: Dict[str, Any],
     return xor_n / max(ref_n, 1)
 
 
-def _gaps(trees: List[Dict[str, Any]], s: Dict[str, np.ndarray], sh, P: int,
-          quant: bool) -> float:
+def _gaps(fam, trees: List[Dict[str, Any]], s: Dict[str, np.ndarray], sh,
+          P: int, quant: bool) -> float:
     """The widest gap over one sampled request's positions served by the
     versions in ``trees``; under ``quant``, the gap under ``trees`` of the
     token that the fp8 model puts first."""
@@ -84,7 +85,7 @@ def _gaps(trees: List[Dict[str, Any]], s: Dict[str, np.ndarray], sh, P: int,
     pos = np.arange(T)
     toks = jnp.asarray(s["tokens"][:T])
     nxt = jnp.asarray(s["tokens"][1:T + 1])
-    kv = jnp.zeros((sh.L, 2, T, sh.KV, sh.dh), jnp.float32)
+    kv = fam.init_cache(sh, T)
     kv_low = kv
     gap = 0.0
     for v, w in enumerate(trees):
@@ -93,11 +94,11 @@ def _gaps(trees: List[Dict[str, Any]], s: Dict[str, np.ndarray], sh, P: int,
             continue
         done = jnp.asarray(s["versions"] < v)
         mask = jnp.asarray(seg & (pos >= P - 1))
-        logits, kv = R.segment_logits(w, toks, sh, kv, done,
-                                      jnp.asarray(seg))
+        logits, kv = fam.segment_logits(w, toks, sh, kv, done,
+                                        jnp.asarray(seg))
         if quant:
-            low, kv_low = R.segment_logits(w, toks, sh, kv_low, done,
-                                           jnp.asarray(seg), True)
+            low, kv_low = fam.segment_logits(w, toks, sh, kv_low, done,
+                                             jnp.asarray(seg), True)
             g = R.control_gaps(logits, low, mask)
             del low
         else:
@@ -107,13 +108,14 @@ def _gaps(trees: List[Dict[str, Any]], s: Dict[str, np.ndarray], sh, P: int,
     return gap
 
 
-def compare(cfg: Dict[str, Any], cell: Dict[str, Any], seed: int,
+def compare(fam, cfg: Dict[str, Any], cell: Dict[str, Any], seed: int,
             tokens: np.ndarray, labels: np.ndarray,
             samples: List[Dict[str, np.ndarray]],
             drains: List[Dict[str, Any]],
             first_bits: Optional[Dict[str, np.ndarray]],
             control: bool = False) -> Dict[str, Any]:
-    """``samples``: served sequences with the version that served each
+    """``fam``: the configuration's family (``bench/families/``);
+    ``samples``: served sequences with the version that served each
     position (0, 1, or later, which is not compared); ``drains``:
     ``{"domain", "prog_domain", "prog_stop"}`` of the window's drains in
     order; ``first_bits``: the elements the first publication changed in
@@ -121,23 +123,23 @@ def compare(cfg: Dict[str, Any], cell: Dict[str, Any], seed: int,
     nothing or the capture failed.  Returns ``decode_gap`` (and
     ``control_gap`` under ``control``) and, where the cell drains,
     ``edit_mismatch`` and ``drain_mismatch``."""
-    sh = R.Shape(cfg)
+    sh = fam.Shape(cfg)
     unl = dict(cell["unlearn"], tau=float(cell["tau"]))
     P = cell["prompt_len"]
-    w = Wt.make_weights(cfg, seed)
+    w = Wt.make_weights(fam, cfg, seed)
     trees = [w]
     out: Dict[str, Any] = {}
     if drains:
         out["drain_mismatch"] = sum(
             1 for d in drains
             if d["prog_domain"] != d["domain"]
-            or d["prog_stop"] != R.stop_layer(sh, unl))
+            or d["prog_stop"] != R.stop_layer(R.n_blocks(fam, sh), unl))
         retain = jnp.asarray(tokens[:int(unl["retain_sample"])])
-        i_g = R.global_fisher(w, retain, sh, int(unl["fisher_chunk"]),
+        i_g = R.global_fisher(fam, w, retain, sh, int(unl["fisher_chunk"]),
                               float(unl["z_loss_global"]))
         rows = tokens[labels == drains[0]["domain"]][:cell["forget_set"]]
         grad_rms: Dict[str, np.ndarray] = {}
-        new, _ = R.drain(w, i_g, jnp.asarray(rows), sh, unl, grad_rms)
+        new, _ = R.drain(fam, w, i_g, jnp.asarray(rows), sh, unl, grad_rms)
         del i_g
         trees.append(new)
         if first_bits is None:
@@ -146,9 +148,9 @@ def compare(cfg: Dict[str, Any], cell: Dict[str, Any], seed: int,
             med = float(np.median(np.concatenate(list(grad_rms.values()))))
             counted = {k: r >= GRAD_FLOOR * med for k, r in grad_rms.items()}
             out["edit_mismatch"] = _mismatch(first_bits, new, w, counted)
-    out["decode_gap"] = max([_gaps(trees, s, sh, P, False)
+    out["decode_gap"] = max([_gaps(fam, trees, s, sh, P, False)
                              for s in samples] or [0.0])
-    out["control_gap"] = (max([_gaps(trees, s, sh, P, True)
+    out["control_gap"] = (max([_gaps(fam, trees, s, sh, P, True)
                                for s in samples] or [0.0])
                           if control else None)
     return out

@@ -21,28 +21,27 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 
-def _programs(cfg, cm, one_chip):
+def _programs(fam, cfg, cm, one_chip):
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     import system
-    import weights as Wt
-    system._program()
+    _, _, _, LMConfig = system._program()
     from repro.core import adapters, fisher
     from repro.core.schedule import checkpoint_set
     from repro.engine.sweep import SweepPlan, build_sweep_program
     from repro.launch.serve import StreamEngine
     from repro.models import lm as LM
 
-    lc = system.lm_config(cfg)
+    lc = fam.lm_config(cfg, LMConfig)
     dt = jnp.dtype(cfg["torch_dtype"])
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    w = {k: sds(s, dt) for k, s in Wt.shapes(cfg).items()}
-    params = system.program_tree(w)
+    w = {k: sds(s, dt) for k, s in fam.shapes(cfg).items()}
+    params = fam.program_tree(w)
     f32 = jax.tree_util.tree_map(lambda a: sds(a.shape, jnp.float32), params)
     P, G, B = cm["prompt_len"], cm["output_len"], cm["pool_width"]
     eng = StreamEngine(params, lc, gen_len=G, prompt_len=P, max_batch=B)
@@ -101,7 +100,7 @@ def main(argv):
         cell = reg.cell(name)
         cm = dict(reg.mix(cell["traffic"]), **cell)
         cfg = reg.config(cell["config"])
-        for prog, lowered in _programs(cfg, cm, one_chip):
+        for prog, lowered in _programs(reg.family(cfg), cfg, cm, one_chip):
             m = lowered.compile().memory_analysis()
             print(f"{name} {prog}: arguments {m.argument_size_in_bytes} "
                   f"output {m.output_size_in_bytes} temporaries "

@@ -22,7 +22,6 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 import check
-import flops
 import loop
 import stats
 import system
@@ -62,7 +61,7 @@ class RunView:
     """What a per-layer metric reader may read of a finished run."""
 
     def __init__(self, cfg, cell, peak, window, drain_spans, n_warm_drains,
-                 trace):
+                 trace, flops):
         self.cfg = cfg
         self.cell = cell
         self.peak = peak
@@ -70,6 +69,7 @@ class RunView:
         self.drain_spans = drain_spans
         self.n_warm_drains = n_warm_drains
         self.trace = trace
+        # the configuration's family: its operation and byte counts
         self.flops = flops
 
     def program(self, name: str):
@@ -192,6 +192,7 @@ def setup(workload: str, seed: int, seconds: float, *, root: str = ROOT,
     st.reg = reg = Registry(root)
     st.cell = reg.cell(workload)
     st.cfg = cfg = reg.config(st.cell["config"])
+    st.family = fam = reg.family(cfg)
     st.cm = cm = dict(reg.mix(st.cell["traffic"]), **st.cell,
                       **(overrides or {}))
     import jax
@@ -214,12 +215,12 @@ def setup(workload: str, seed: int, seconds: float, *, root: str = ROOT,
 
     st.forget = float(cm.get("forget_rate", 0.0)) > 0
     st.sched = generator.schedule(cm, seed, seconds)
-    w0 = Wt.make_weights(cfg, seed)
+    w0 = Wt.make_weights(fam, cfg, seed)
     st.tokens, st.labels = Wt.make_domains(cfg, cm, seed)
     st.prompts = Wt.make_prompts(cfg, len(st.sched["generate"]) + 8,
                                  cm["prompt_len"], seed)
     st.srv = srv = system.build_server(
-        cfg, w0, st.tokens, st.labels, cm["forget_len"] + 1, cm, cdir,
+        fam, cfg, w0, st.tokens, st.labels, cm["forget_len"] + 1, cm, cdir,
         precision="int8" if control else "fp32")
     del w0
     srv.time_drains(clock)
@@ -267,7 +268,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     srv, cm, cfg, reg, clock = st.srv, st.cm, st.cfg, st.reg, st.clock
     devs, chips, kind, peak = st.devs, st.chips, st.kind, st.peak
     compiles, sched, prompts = st.compiles, st.sched, st.prompts
-    forget, setup_s = st.forget, st.setup_s
+    forget, setup_s, fam = st.forget, st.setup_s, st.family
     tokens, labels, cell, n_log0 = st.tokens, st.labels, st.cell, st.n_log0
     del st
 
@@ -337,7 +338,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         device["window_s"] = span[1] - span[0]
         view = RunView(cfg, cm, peak, win,
                        [[a - win.t0, b - win.t0] for a, b in srv.drain_spans],
-                       int(forget), tr)
+                       int(forget), tr, fam)
         for m in reg.per_layer(workload):
             val = reg.metric_reader(m["name"])(view)
             if val is not None:
@@ -377,7 +378,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     del srv
     gc.collect()
     t_ref = clock()
-    got = check.compare(cfg, cm, seed, tokens, labels, samples, drains,
+    got = check.compare(fam, cfg, cm, seed, tokens, labels, samples, drains,
                         first_bits, control=control)
     log(f"reference: {clock() - t_ref:.3f} s; {len(samples)} sampled "
         f"requests, {sum(int((s['versions'] == 0).sum()) for s in samples)} "

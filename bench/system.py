@@ -4,15 +4,16 @@ Every call into the program (``repro``, under ``src/``) goes through this
 file, so the rest of the harness — traffic, timing, the reference and the
 comparison — depends on nothing of the program but what is named here:
 
-  * ``lm_config``        a configuration file -> the program's ``LMConfig``;
-  * ``program_tree`` / ``neutral_tree``
-                         the benchmark's own weight layout <-> the program's
-                         parameter tree (the same arrays, re-nested);
   * ``build_server``     ``ForgetService`` (scanned sweep, the program's own
                          defaults for chunking, admission and publication)
                          under a ``StreamEngine`` sized by the cell;
   * ``Served``           the handful of engine and service attributes the
                          open-loop client reads between steps.
+
+What depends on the model's architecture (its ``LMConfig``, and the
+benchmark's weight layout <-> the program's parameter tree) is the family's
+(``bench/families/<family>.py``: ``lm_config``, ``program_tree``,
+``neutral_tree``), handed in by the caller.
 """
 from __future__ import annotations
 
@@ -34,58 +35,13 @@ def _program():
     return ServeSpec, ForgetService, StreamEngine, LMConfig
 
 
-def lm_config(cfg: Dict[str, Any]):
-    """The program's ``LMConfig`` for a configuration file (HF key names)."""
-    _, _, _, LMConfig = _program()
-    return LMConfig(
-        name=cfg["name"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab=cfg["vocab_size"], head_dim=cfg["head_dim"],
-        qkv_bias=cfg["attention_bias"], rope_theta=cfg["rope_theta"],
-        prefix_len=cfg.get("num_image_token", 0),
-        tie_embeddings=cfg["tie_word_embeddings"],
-        param_dtype=cfg["torch_dtype"])
-
-
-_BLOCK = {"ln1": ("ln1", "scale"), "ln2": ("ln2", "scale"),
-          "wq": ("mixer", "wq"), "wk": ("mixer", "wk"), "wv": ("mixer", "wv"),
-          "wo": ("mixer", "wo"), "bq": ("mixer", "bq"), "bk": ("mixer", "bk"),
-          "bv": ("mixer", "bv"), "w_gate": ("ffn", "w_gate"),
-          "w_up": ("ffn", "w_up"), "w_down": ("ffn", "w_down")}
-
-
-def program_tree(w: Dict[str, Any]) -> Dict[str, Any]:
-    """Benchmark layout (flat dict, block leaves stacked ``[L, ...]``) ->
-    the program's tree (``period_stack`` of a one-block pattern)."""
-    blk: Dict[str, Dict[str, Any]] = {}
-    for name, (grp, leaf) in _BLOCK.items():
-        if name in w:
-            blk.setdefault(grp, {})[leaf] = w[name]
-    return {"embed": {"w": w["embed"]},
-            "final_norm": {"scale": w["final_norm"]},
-            "period_stack": {"0": blk},
-            "lm_head": {"w": w["lm_head"]}}
-
-
-def neutral_tree(p: Dict[str, Any]) -> Dict[str, Any]:
-    """The program's tree -> the benchmark layout (inverse of
-    ``program_tree``)."""
-    blk = p["period_stack"]["0"]
-    w = {"embed": p["embed"]["w"], "final_norm": p["final_norm"]["scale"],
-         "lm_head": p["lm_head"]["w"]}
-    for name, (grp, leaf) in _BLOCK.items():
-        if leaf in blk.get(grp, {}):
-            w[name] = blk[grp][leaf]
-    return w
-
-
 class Served:
     """The engine and its forget service, with the reads the client makes."""
 
-    def __init__(self, svc, engine):
+    def __init__(self, svc, engine, family):
         self.svc = svc
         self.engine = engine
+        self.family = family
         self.drain_spans: List[List[float]] = []   # [start, end] per sweep
 
     # -- traffic -----------------------------------------------------------
@@ -138,7 +94,7 @@ class Served:
 
     def served_tree(self):
         """The tree the decode step reads (benchmark layout)."""
-        return neutral_tree(self.engine.params)
+        return self.family.neutral_tree(self.engine.params)
 
     def warm_drain(self, domain: int) -> bool:
         """One drain through the engine's own sweep entry, on the live
@@ -170,24 +126,25 @@ class Served:
         self.engine.finish()
 
 
-def build_server(cfg: Dict[str, Any], weights: Dict[str, Any], tokens,
+def build_server(fam, cfg: Dict[str, Any], weights: Dict[str, Any], tokens,
                  domains, seq_len: int, cell: Dict[str, Any], cache_dir: str,
                  precision: str = "fp32") -> Served:
-    """The served deployment of one cell: a one-tenant ``ForgetService``
+    """The served deployment of one cell, for a model of family ``fam``
+    with ``weights`` in that family's layout: a one-tenant ``ForgetService``
     (scanned sweep, step publication) under a ``StreamEngine`` of the cell's
     pool width and lengths.  Chunking, admission width, prefill block and
     publication lag are the program's own defaults."""
-    ServeSpec, ForgetService, StreamEngine, _ = _program()
-    lcfg = lm_config(cfg)
+    ServeSpec, ForgetService, StreamEngine, LMConfig = _program()
+    lcfg = fam.lm_config(cfg, LMConfig)
     serve = ServeSpec(cache_dir=cache_dir, sweep_mode="scanned",
                       publish="step", precision=precision,
                       max_batch=cell["pool_width"], tau=float(cell["tau"]),
                       max_forget_samples=cell["forget_set"])
     svc = ForgetService(lcfg, tokens, domains, seq_len, serve=serve)
-    eng = StreamEngine(program_tree(weights), lcfg,
+    eng = StreamEngine(fam.program_tree(weights), lcfg,
                        gen_len=cell["output_len"],
                        prompt_len=cell["prompt_len"],
                        max_batch=serve.max_batch,
                        admit_chunk=serve.admit_chunk,
                        publish_lag=serve.publish_lag, service=svc)
-    return Served(svc, eng)
+    return Served(svc, eng, fam)

@@ -1,9 +1,11 @@
 """Every cell, configuration, mix and metric of BENCHMARK.json is found by
-name, and a new cell is taken by adding its file alone."""
+name, and a new cell, or a new model family, is taken by adding its files
+alone."""
 import json
 import os
 import sys
 
+import numpy as np
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -77,3 +79,160 @@ def test_unknown_names_are_errors():
         reg.cell("no-such-cell")
     with pytest.raises(KeyError):
         reg.metric_reader("no_such_metric")
+
+
+# A test-only family: the dense math, with block 0 stored apart from blocks
+# 1..L-1 (two segments), the layout a dense block in front of expert blocks
+# needs.  It re-nests into the program's one-kind ``period_stack``.
+DENSE_SPLIT = '''
+import importlib.util
+import os
+
+import jax.numpy as jnp
+
+import reference as R
+
+_spec = importlib.util.spec_from_file_location(
+    "dense_for_split", os.path.join(os.path.dirname(__file__), "dense.py"))
+D = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(D)
+
+MODEL_TYPES = ("dense-split-test",)
+FIRST = "first."
+Shape, HEAD_LEAVES, head, init_cache = D.Shape, D.HEAD_LEAVES, D.head, \\
+    D.init_cache
+lm_config = D.lm_config
+decode_step_flops, decode_step_bytes = D.decode_step_flops, \\
+    D.decode_step_bytes
+forward_flops, drain_flops = D.forward_flops, D.drain_flops
+
+
+def split(w):
+    out = {}
+    for k, a in w.items():
+        if k in D.BLOCK_LEAVES:
+            out[FIRST + k], out[k] = a[:1], a[1:]
+        else:
+            out[k] = a
+    return out
+
+
+def join(w):
+    out = {k: a for k, a in w.items() if not k.startswith(FIRST)}
+    for k in D.BLOCK_LEAVES:
+        if k in w:
+            out[k] = jnp.concatenate([w[FIRST + k], w[k]])
+    return out
+
+
+def shapes(cfg):
+    out = {}
+    for k, s in D.shapes(cfg).items():
+        if k in D.BLOCK_LEAVES:
+            out[FIRST + k], out[k] = (1,) + s[1:], (s[0] - 1,) + s[1:]
+        else:
+            out[k] = s
+    return out
+
+
+def init(key, cfg):
+    return split(D.init(key, cfg))
+
+
+def program_tree(w):
+    return D.program_tree(join(w))
+
+
+def neutral_tree(p):
+    return split(D.neutral_tree(p))
+
+
+def segments(sh):
+    (seg,) = D.segments(sh)
+    return [R.Segment(D.block, {k: FIRST + k for k in seg.leaves}, 1),
+            R.Segment(D.block, dict(seg.leaves), sh.L - 1)]
+
+
+def segment_logits(w, tokens, sh, kv, done, seg, quant=False):
+    return D.segment_logits(join(w), tokens, sh, kv, done, seg, quant)
+'''
+
+
+def _tree_digest(root):
+    import hashlib
+    out = {}
+    for dirpath, dirs, files in os.walk(root):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for f in files:
+            p = os.path.join(dirpath, f)
+            with open(p, "rb") as fh:
+                out[os.path.relpath(p, root)] = hashlib.sha256(
+                    fh.read()).hexdigest()
+    return out
+
+
+def test_a_new_family_is_taken_by_adding_its_file(tmp_path,
+                                                  restore_jax_cache):
+    import jax.numpy as jnp
+    import harness
+    import reference as R
+    import weights as Wt
+    root = tiny.make_root(str(tmp_path))
+    before = _tree_digest(root)
+    b = os.path.join(root, "bench")
+    cfg = dict(tiny.TINY_CONFIG, name="tiny-split",
+               model_type="dense-split-test")
+    added = {os.path.join("bench", "families", "dense_split.py"): DENSE_SPLIT,
+             os.path.join("bench", "configs", "tiny-split.json"):
+                 json.dumps(cfg),
+             os.path.join("bench", "workloads", "tiny-split.chat-forget.json"):
+                 json.dumps(dict(tiny.tiny_cell("tiny-split.chat-forget"),
+                                 config="tiny-split"))}
+    for rel, text in added.items():
+        with open(os.path.join(root, rel), "w") as f:
+            f.write(text)
+
+    # the CPU harness end to end, as test_sound_run_is_correct runs it
+    out = harness.run("tiny-split.chat-forget", 5, 3.0, False, root=root,
+                      require_tpu=False, hooks={"peak": tiny.CPU_PEAK})
+    assert out["correct"], out["checks"]
+    assert out["checks"]["drain_mismatch"]["value"] == 0
+
+    # the same weights in the two layouts: the same Fisher and drain
+    reg = Registry(root)
+    split, dense = reg.family(cfg), reg.family(tiny.TINY_CONFIG)
+    assert split is not dense
+    w = Wt.make_weights(dense, tiny.TINY_CONFIG, 11)
+    ws = Wt.make_weights(split, cfg, 11)
+    assert split.join(ws).keys() == w.keys()
+    for k in w:
+        assert (np.asarray(split.join(ws)[k]) == np.asarray(w[k])).all(), k
+    sh = dense.Shape(tiny.TINY_CONFIG)
+    tokens, labels = Wt.make_domains(tiny.TINY_CONFIG, tiny.TINY_MIX, 11)
+    retain, rows = jnp.asarray(tokens[:32]), jnp.asarray(
+        tokens[labels == 2][:8])
+    unl = dict(tiny.UNLEARN, tau=-1.0)
+    got, want = {}, {}
+    for fam, weights, res in ((split, ws, got), (dense, w, want)):
+        res["fisher"] = R.global_fisher(fam, weights, retain, sh, 4, 1e-4)
+        res["drain"], res["stop"] = R.drain(fam, weights, res["fisher"],
+                                            rows, sh, unl)
+    assert got["stop"] == want["stop"]
+    for part in ("fisher", "drain"):
+        joined = split.join(got[part])
+        assert joined.keys() == want[part].keys()
+        for k, a in want[part].items():
+            assert (np.asarray(joined[k]) == np.asarray(a)).all(), (part, k)
+
+    # an unknown model_type fails at set-up, naming it
+    with open(os.path.join(b, "configs", "tiny-split.json"), "w") as f:
+        json.dump(dict(cfg, model_type="no-such-family"), f)
+    with pytest.raises(KeyError, match="no-such-family") as e:
+        harness.setup("tiny-split.chat-forget", 5, 1.0, root=root,
+                      require_tpu=False, hooks={"peak": tiny.CPU_PEAK})
+    assert "dense_split.py" in str(e.value) and "dense.py" in str(e.value)
+
+    # no file of the tree was changed to admit the family: three were added
+    after = _tree_digest(root)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert set(after) - set(before) == set(added)

@@ -289,7 +289,7 @@ def test_older_recording_reads_as_before():
     import cut_trace
     tr = xplane.reduce_profile(cut_trace.load(RECORDED_NO_SPANS))
     reg = Registry()
-    view = harness.RunView(None, None, None, None, [], 0, tr)
+    view = harness.RunView(None, None, None, None, [], 0, tr, None)
     assert reg.metric_reader("decode_step_ms")(view) == pytest.approx(
         NO_SPANS["decode_step_ms"], rel=1e-12)
     assert reg.metric_reader("prefill_ms")(view) == pytest.approx(

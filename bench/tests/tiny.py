@@ -11,7 +11,8 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 
 TINY_CONFIG = {
-    "name": "tiny", "source": "test only", "hidden_size": 64,
+    "name": "tiny", "source": "test only", "model_type": "qwen2",
+    "hidden_size": 64,
     "intermediate_size": 160, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 256, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,

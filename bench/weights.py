@@ -1,21 +1,18 @@
 """Weights, forget domains and prompts, made by the benchmark from ``--seed``.
 
 The weights are made on the device in one jitted call, in the type they are
-served in, in the benchmark's own layout: a flat dict whose block leaves are
-stacked ``[L, ...]`` (``system.program_tree`` re-nests the same arrays for
-the program).  The reference reads these arrays and nothing the program
-made; it regenerates them from the seed when it needs them again.
+served in, in the benchmark's own layout, which the model's family defines
+(``bench/families/<family>.py``: ``shapes``, ``init``; its ``program_tree``
+re-nests the same arrays for the program).  The reference reads these
+arrays and nothing the program made; it regenerates them from the seed when
+it needs them again.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-
-F32 = jnp.float32
 
 
 def prng_key(seed: int) -> jax.Array:
@@ -25,43 +22,12 @@ def prng_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
 
 
-def shapes(cfg: Dict[str, Any]) -> Dict[str, Tuple[int, ...]]:
-    L, D = cfg["num_hidden_layers"], cfg["hidden_size"]
-    H, KV, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    F, V = cfg["intermediate_size"], cfg["vocab_size"]
-    s = {"embed": (V, D), "final_norm": (D,), "lm_head": (D, V),
-         "ln1": (L, D), "ln2": (L, D), "wq": (L, D, H * dh),
-         "wk": (L, D, KV * dh), "wv": (L, D, KV * dh), "wo": (L, H * dh, D),
-         "w_gate": (L, D, F), "w_up": (L, D, F), "w_down": (L, F, D)}
-    if cfg["attention_bias"]:
-        s.update(bq=(L, H * dh), bk=(L, KV * dh), bv=(L, KV * dh))
-    return s
-
-
-def _init(key, cfg):
-    dt = jnp.dtype(cfg["torch_dtype"])
-    shp = shapes(cfg)
-    keys = dict(zip(sorted(shp), jax.random.split(key, len(shp))))
-    out = {}
-    for name in sorted(shp):
-        k, s = keys[name], shp[name]
-        if name in ("ln1", "ln2", "final_norm"):
-            w = 1.0 + 0.1 * jax.random.normal(k, s, F32)
-        elif name in ("bq", "bk", "bv"):
-            w = 0.02 * jax.random.normal(k, s, F32)
-        elif name == "embed":
-            w = 0.02 * jax.random.normal(k, s, F32)
-        else:   # fan-in scaled, as the published initialisers do
-            w = jax.random.truncated_normal(k, -2.0, 2.0, s, F32) \
-                / math.sqrt(s[-2])
-        out[name] = w.astype(dt)
-    return out
-
-
-def make_weights(cfg: Dict[str, Any], seed: int) -> Dict[str, jax.Array]:
-    """All weights, on the default device, from one jitted call."""
-    return jax.jit(_init, static_argnums=1)(prng_key(seed), _Hashable(cfg))
+def make_weights(fam, cfg: Dict[str, Any], seed: int
+                 ) -> Dict[str, jax.Array]:
+    """All weights of the configuration's family ``fam``, on the default
+    device, from one jitted call."""
+    return jax.jit(fam.init, static_argnums=1)(prng_key(seed),
+                                               _Hashable(cfg))
 
 
 class _Hashable(dict):
