@@ -23,6 +23,7 @@ import numpy as np
 
 import check
 import loop
+import spans
 import stats
 import system
 import xplane
@@ -61,7 +62,7 @@ class RunView:
     """What a per-layer metric reader may read of a finished run."""
 
     def __init__(self, cfg, cell, peak, window, drain_spans, n_warm_drains,
-                 trace, flops):
+                 trace, flops, program_trace, counters):
         self.cfg = cfg
         self.cell = cell
         self.peak = peak
@@ -71,6 +72,13 @@ class RunView:
         self.trace = trace
         # the configuration's family: its operation and byte counts
         self.flops = flops
+        # the program's own spans and the named scopes of its device ops
+        # (``spans.ProgramTrace``), None untraced
+        self.program_trace = program_trace
+        # the engine's and the drain worker's counters over the window
+        # (``spans.counter_delta`` of ``Served.counters``): every key their
+        # ``stats()`` gives, nested dicts kept
+        self.counters = counters
 
     def program(self, name: str):
         """(device seconds, launches) of a program in the trace, or None."""
@@ -78,6 +86,15 @@ class RunView:
             return None
         got = self.trace.program_seconds().get(name)
         return got if got and got[1] > 0 else None
+
+    def scoped(self, program: str, scope: str):
+        """(device seconds of the leaf ops in the named scope ``scope``
+        inside ``program``'s launches, device seconds of those launches,
+        launches), summed over the devices; None untraced or where the
+        program has no launch."""
+        if self.program_trace is None:
+            return None
+        return spans.scoped(self.program_trace, program, scope)
 
 
 def peak_for(peaks: Dict[str, Any], kind: str) -> Dict[str, Any]:
@@ -261,7 +278,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         keep_trace: Optional[str] = None) -> Dict[str, Any]:
     """Run one cell once; returns the result line's object.  ``control``
     also reads the fp8 reference as the served tokens; ``keep_trace``
-    names a directory that receives a copy of the profiler trace."""
+    names a directory that receives a copy of the profiler trace;
+    ``hooks["view"](view)`` receives the ``RunView`` of a traced run."""
+    hooks = hooks or {}
     st = setup(workload, seed, seconds, root=root, require_tpu=require_tpu,
                control=control, t_start=t_start, hooks=hooks)
     import jax
@@ -304,9 +323,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             cap["bits"] = {k: np.asarray(b) for k, b in cap["bits"].items()}
 
     c0 = compiles.n
+    before = srv.counters()
     win = loop.serve(srv, sched, prompts, seconds=seconds,
                      gen_len=cm["output_len"], clock=clock, trace=tspec,
                      on_publish=on_publish)
+    counters = spans.counter_delta(before, srv.counters())
     cap["base"] = None
     n_compiles = compiles.n - c0
     srv.close()
@@ -329,6 +350,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     metrics: Dict[str, Dict[str, Any]] = {}
     breakdown = None
     if trace:
+        t0 = clock()
         tr = xplane.load(tdir)
         span = win.trace_span
         progs = sorted(tr.program_seconds().items(), key=lambda kv: -kv[1][0])
@@ -336,9 +358,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             f"{k} {s:.6f} {n}" for k, (s, n) in progs[:12]))
         device["busy_s"] = tr.busy_s()
         device["window_s"] = span[1] - span[0]
+        t1 = clock()
+        pt = spans.load(tdir)
+        t2 = clock()
         view = RunView(cfg, cm, peak, win,
                        [[a - win.t0, b - win.t0] for a, b in srv.drain_spans],
-                       int(forget), tr, fam)
+                       int(forget), tr, fam, pt, counters)
+        if "view" in hooks:
+            hooks["view"](view)
         for m in reg.per_layer(workload):
             val = reg.metric_reader(m["name"])(view)
             if val is not None:
@@ -347,7 +374,16 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                 log(f"MISSING per-layer metric {m['name']}: its reader "
                     f"found nothing in the trace (programs seen: "
                     f"{sorted(k for k, _ in progs)})")
+        t3 = clock()
         breakdown = xplane.breakdown(tr)
+        if breakdown is not None:
+            breakdown["idle_gaps"] = [[k, v]
+                                      for k, v in spans.idle_gaps(tr, pt)]
+        log(f"trace read in {clock() - t0:.3f} s after the window: profile "
+            f"{t1 - t0:.3f} s, program trace {t2 - t1:.3f} s "
+            f"({len(pt.spans)} spans, "
+            f"{sum(len(d.ops) for d in pt.devices)} leaf ops), readers "
+            f"{t3 - t2:.3f} s, breakdown {clock() - t3:.3f} s")
         import shutil
         if keep_trace:
             shutil.copytree(tdir, keep_trace, dirs_exist_ok=True)

@@ -21,7 +21,7 @@ read, as on a program without these spans):
   * ``admit_host_ms``: mean duration of ``ficabu.engine.admit``;
   * ``sweep_checkpoint_pct``: the union of ``checkpoint``-scoped leaf-op
     intervals inside ``jit_sweep`` launches over the union of those
-    launches;
+    launches (``scoped``, which reads any scope of any program);
   * ``publish_wait_ms`` and ``drain_host_ms``: from the engine's and the
     drain worker's counters over a window (``counter_delta``).
 
@@ -47,7 +47,6 @@ SCOPE = "checkpoint"
 # the stat of a device op (its event's, else its metadata's) that carries
 # its HLO op_name
 OP_NAME_STAT = "tf_op"
-_SCOPE_RE = re.compile(r"(^|/)" + SCOPE + r"(/|:|$)")
 
 
 class Span(NamedTuple):
@@ -283,30 +282,51 @@ def admit_host_ms(pt: ProgramTrace) -> Optional[float]:
     return sum(admits) / len(admits) * 1e3 if admits else None
 
 
-def checkpoint_share(dev: Device) -> Optional[Tuple[float, float]]:
-    """(checkpoint-scoped seconds, sweep seconds) of one device: the union
-    of scoped leaf-op intervals clipped to ``jit_sweep`` launches, and the
-    union of those launches; None with no launch."""
-    sweeps = xplane.union((a, b) for n, a, b in dev.modules
-                          if xplane.program_name(n) == SWEEP_PROGRAM)
-    if not sweeps:
+def scope_re(scope: str) -> "re.Pattern":
+    """Matches an ``op_name`` that holds ``scope`` as a path component
+    (``jit(sweep)/while/checkpoint/dot_general:``)."""
+    return re.compile(r"(^|/)" + re.escape(scope) + r"(/|:|$)")
+
+
+def scoped_share(dev: Device, program: str, scope: str
+                 ) -> Optional[Tuple[float, float, int]]:
+    """(scope seconds, program seconds, launches) of one device: the union
+    of the leaf-op intervals in the named scope ``scope``, clipped to the
+    launches of ``program``; the union of those launches; their count.
+    None where the program has no launch."""
+    launches = [(a, b) for n, a, b in dev.modules
+                if xplane.program_name(n) == program]
+    runs = xplane.union(launches)
+    if not runs:
         return None
-    starts = [a for a, _ in sweeps]
+    starts = [a for a, _ in runs]
+    pat = scope_re(scope)
     scoped = []
     for o in dev.ops:
-        if not _SCOPE_RE.search(o.op_name):
+        if not pat.search(o.op_name):
             continue
         i = bisect.bisect_right(starts, o.start) - 1
-        if i >= 0 and o.start < sweeps[i][1]:
-            scoped.append((o.start, min(o.end, sweeps[i][1])))
-    return xplane.total(xplane.union(scoped)), xplane.total(sweeps)
+        if i >= 0 and o.start < runs[i][1]:
+            scoped.append((o.start, min(o.end, runs[i][1])))
+    return (xplane.total(xplane.union(scoped)), xplane.total(runs),
+            len(launches))
+
+
+def scoped(pt: ProgramTrace, program: str, scope: str
+           ) -> Optional[Tuple[float, float, int]]:
+    """``scoped_share`` summed over the devices; None where the program has
+    no launch on any."""
+    got = [g for g in (scoped_share(d, program, scope) for d in pt.devices)
+           if g is not None]
+    if not got:
+        return None
+    return (sum(g[0] for g in got), sum(g[1] for g in got),
+            sum(g[2] for g in got))
 
 
 def sweep_checkpoint_pct(pt: ProgramTrace) -> Optional[float]:
-    got = [c for c in map(checkpoint_share, pt.devices) if c is not None]
-    if not got:
-        return None
-    return 100.0 * sum(c for c, _ in got) / sum(s for _, s in got)
+    got = scoped(pt, SWEEP_PROGRAM, SCOPE)
+    return None if got is None else 100.0 * got[0] / got[1]
 
 
 # -- readings from counters --------------------------------------------------
@@ -350,20 +370,12 @@ def idle_gaps(tr: "xplane.Trace", pt: ProgramTrace, n: int = 10
     """``xplane.Trace.idle_gaps`` with each name followed by
     ``/<innermost ficabu.* span of the engine thread covering the gap>``
     where one covers it."""
-    if not tr.devices:
-        return []
     thread = engine_thread(pt)
     eng = [s for s in pt.spans if s.thread == thread]
-    lo, hi = tr.bounds()
     out = []
-    for a, b in xplane.gaps(tr.devices[0].busy, lo, hi):
-        best, cover = "host.other", 0.0
-        for name, s, e in tr.host_spans:
-            ov = min(b, e) - max(a, s)
-            if ov > cover:
-                best, cover = name, ov
+    for best, a, b in tr.named_gaps(n):
         inner = [s for s in eng if s.start <= a and b <= s.end]
         if inner:
             best += "/" + min(inner, key=lambda s: s.end - s.start).name
         out.append((best, b - a))
-    return sorted(out, key=lambda kv: -kv[1])[:n]
+    return out

@@ -8,7 +8,8 @@ comparison — depends on nothing of the program but what is named here:
                          defaults for chunking, admission and publication)
                          under a ``StreamEngine`` sized by the cell;
   * ``Served``           the handful of engine and service attributes the
-                         open-loop client reads between steps.
+                         open-loop client reads between steps, and their
+                         counters, read around the window.
 
 What depends on the model's architecture (its ``LMConfig``, and the
 benchmark's weight layout <-> the program's parameter tree) is the family's
@@ -79,6 +80,11 @@ class Served:
         e = self.engine
         return bool(e.pending or any(s is not None for s in e.slot_seq)
                     or e._pending_pubs or self.svc.scheduler.pending())
+
+    def counters(self) -> Dict[str, Any]:
+        """The engine's and the forget service's own counters
+        (``StreamEngine.stats``, ``ForgetService.stats``)."""
+        return {"engine": self.engine.stats(), "drain": self.svc.stats()}
 
     def results(self) -> Dict[int, Any]:
         return self.engine.results

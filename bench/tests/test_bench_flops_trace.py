@@ -150,7 +150,8 @@ def test_recorded_tpu_trace_reduces_to_programs_and_readers():
     bd = xplane.breakdown(tr)
     assert 0 < len(bd["device_ops"]) <= 10
     assert 0 < len(bd["idle_gaps"]) <= 10
-    view = harness.RunView(None, None, None, None, [], 0, tr, None)
+    view = harness.RunView(None, None, None, None, [], 0, tr, None, None,
+                           None)
     step_ms = Registry().metric_reader("decode_step_ms")(view)
     assert 2.0 < step_ms < 2.5      # 1.26 GB of weights at 819 GB/s: 1.5
     pre = Registry().metric_reader("prefill_ms")(view)

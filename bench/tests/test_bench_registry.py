@@ -1,6 +1,6 @@
 """Every cell, configuration, mix and metric of BENCHMARK.json is found by
-name, and a new cell, or a new model family, is taken by adding its files
-alone."""
+name, and a new cell, a new model family, or a new per-layer metric that
+reads a new named scope and counter, is taken by adding its files alone."""
 import json
 import os
 import sys
@@ -236,3 +236,107 @@ def test_a_new_family_is_taken_by_adding_its_file(tmp_path,
     after = _tree_digest(root)
     assert {k: v for k, v in after.items() if k in before} == before
     assert set(after) - set(before) == set(added)
+
+
+# A test-only per-layer metric of a kind no program has yet: device time in
+# an ``experts`` named scope of the decode step, per routed token from a
+# counter the engine does not keep today.
+EXPERTS_METRIC = '''
+"""Test only: decode-step device microseconds in the ``experts`` scope per
+routed token."""
+
+
+def read(run):
+    got = run.scoped("jit__step", "experts")
+    tokens = run.counters.get("engine", {}).get("routed_tokens")
+    if got is None or not tokens:
+        return None
+    return got[0] / tokens * 1e6
+'''
+
+
+def _op(mid, start_us, dur_us):
+    return (f"events {{ metadata_id: {mid} offset_ps: {start_us * 10**6} "
+            f"duration_ps: {dur_us * 10**6} }}")
+
+
+def _op_meta(mid, name, op_name=None):
+    st = ("" if op_name is None else
+          f'stats {{ metadata_id: 1 str_value: {json.dumps(op_name)} }}')
+    return (f"event_metadata {{ key: {mid} value {{ id: {mid} "
+            f"name: {json.dumps(name)} {st} }} }}")
+
+
+# jit__step launches [0, 4) and [10, 14) us; jit_sweep [5, 8).  In the
+# steps, ``experts`` ops take [0, 1), [3, 4) and [10, 12); an ``attn`` op
+# and an ``experts_gate`` op (another scope) do not count, nor does the
+# sweep's own ``experts`` op.
+EXPERTS_XSPACE = f"""
+planes {{ id: 1 name: "/device:TPU:0"
+  lines {{ id: 1 name: "XLA Modules" timestamp_ns: 1000
+    {_op(11, 0, 4)} {_op(12, 5, 3)} {_op(11, 10, 4)} }}
+  lines {{ id: 2 name: "XLA Ops" timestamp_ns: 1000
+    {_op(21, 0, 1)} {_op(22, 1, 2)} {_op(23, 3, 1)} {_op(24, 5, 2)}
+    {_op(21, 10, 2)} {_op(25, 12, 2)} }}
+  {_op_meta(11, "jit__step(7)")}
+  {_op_meta(12, "jit_sweep(2)")}
+  {_op_meta(21, "fusion.1", "jit(_step)/layers/experts/dot_general:")}
+  {_op_meta(22, "fusion.2", "jit(_step)/layers/attn/dot_general:")}
+  {_op_meta(23, "fusion.3", "jit(_step)/experts/add:")}
+  {_op_meta(24, "fusion.4", "jit(sweep)/experts/dot_general:")}
+  {_op_meta(25, "fusion.5", "jit(_step)/experts_gate/mul:")}
+  stat_metadata {{ key: 1 value {{ id: 1 name: "tf_op" }} }}
+}}
+"""
+
+
+def test_a_new_metric_reads_a_new_scope_and_counter_by_adding_its_file(
+        tmp_path):
+    """A metric that reads a named scope and a counter key no program has
+    today is one new reader file and one ``per_layer`` entry: the
+    harness's ``RunView`` hands it the scoped device time and the counter,
+    and no file of ``bench/`` is edited."""
+    import harness
+    import spans
+    root = tiny.make_root(str(tmp_path))
+    before = _tree_digest(root)
+    rel = os.path.join("bench", "metrics", "experts_us_per_token.py")
+    with open(os.path.join(root, rel), "w") as f:
+        f.write(EXPERTS_METRIC)
+    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    bench["per_layer"].append({
+        "name": "experts_us_per_token", "unit": "us", "better": "lower",
+        "source": "device_trace", "layer": "experts",
+        "moves": "tpot_mean_ms", "workloads": ["tiny.chat-forget"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    reg = Registry(root)
+    assert "experts_us_per_token" in [
+        m["name"] for m in reg.per_layer("tiny.chat-forget")]
+    pt = spans.reduce_xspace(spans.parse_text(EXPERTS_XSPACE))
+    # a key the engine's stats() may add later reaches the reader through
+    # the harness's window delta
+    counters = spans.counter_delta(
+        {"engine": {"steps": 3, "routed_tokens": 100}, "drain": {}},
+        {"engine": {"steps": 5, "routed_tokens": 150}, "drain": {}})
+    view = harness.RunView(None, None, None, None, [], 0, None, None, pt,
+                           counters)
+    assert view.scoped("jit__step", "experts") == (
+        pytest.approx(4e-6), pytest.approx(8e-6), 2)
+    assert view.scoped("jit_sweep", "experts") == (
+        pytest.approx(2e-6), pytest.approx(3e-6), 1)
+    assert view.scoped("jit__admit", "experts") is None
+    read = reg.metric_reader("experts_us_per_token")
+    assert read(view) == pytest.approx(4e-6 / 50 * 1e6)
+    # nothing to read: untraced, or a program without the counter
+    assert read(harness.RunView(None, None, None, None, [], 0, None, None,
+                                None, counters)) is None
+    assert read(harness.RunView(None, None, None, None, [], 0, None, None,
+                                pt, {"engine": {"steps": 2}})) is None
+
+    # one file added under bench/, none changed
+    after = _tree_digest(root)
+    assert set(after) - set(before) == {rel}
+    del before["BENCHMARK.json"]
+    assert {k: after[k] for k in before} == before

@@ -14,6 +14,7 @@ sys.path.insert(0, BENCH)
 sys.path.insert(0, HERE)
 
 import tiny  # noqa: E402
+from registry import Registry  # noqa: E402
 
 
 def _run(tmp_path, seed=5, hook=None, control=False):
@@ -104,6 +105,31 @@ def test_control_is_not_correct(tmp_path, restore_jax_cache):
     assert not out["correct"], out["checks"]
     assert out["checks"]["edit_mismatch"]["value"] > \
         out["checks"]["edit_mismatch"]["limit"]
+
+
+def test_traced_run_hands_readers_the_window_counters(tmp_path,
+                                                     restore_jax_cache):
+    """A traced run hands its readers the engine's and the drain worker's
+    counters over the window (its steps are the window's steps, its
+    publications one per forget request) and the program's own spans."""
+    import harness
+    root = tiny.make_root(str(tmp_path))
+    got = {}
+    out = harness.run("tiny.chat-forget", 7, 3.0, True, root=root,
+                      require_tpu=False,
+                      hooks={"peak": tiny.CPU_PEAK,
+                             "view": lambda v: got.update(view=v)})
+    assert out["correct"], out["checks"]
+    view = got["view"]
+    win, eng = view.window, view.counters["engine"]
+    assert eng["steps"] == win.last_step - win.first_step > 0
+    assert eng["publications"] == len(win.forget_due) > 0
+    assert view.counters["drain"]["groups"] == len(win.forget_due)
+    names = {s.name for s in view.program_trace.spans}
+    assert {"ficabu.engine.step", "ficabu.drain"} <= names
+    reg = Registry(root)
+    for m in ("step_host_ms", "admit_host_ms", "drain_host_ms"):
+        assert reg.metric_reader(m)(view) > 0, m
 
 
 def test_run_py_refuses_without_a_tpu():

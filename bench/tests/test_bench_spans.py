@@ -149,11 +149,13 @@ def test_leaf_ops_and_checkpoint_share(synthetic):
     (dev,) = pt.devices
     assert [o.name for o in dev.ops] == ["fusion.1", "fusion.3", "fusion.5",
                                          "fusion.6", "fusion.7"]
-    scoped = [o.name for o in dev.ops if spans._SCOPE_RE.search(o.op_name)]
+    pat = spans.scope_re("checkpoint")
+    scoped = [o.name for o in dev.ops if pat.search(o.op_name)]
     assert scoped == ["fusion.5", "fusion.6", "fusion.7"]
     assert dict((o.name, o.op_name) for o in dev.ops)["fusion.5"] == REF
-    assert spans.checkpoint_share(dev) == (pytest.approx(7e-6),
-                                           pytest.approx(10e-6))
+    # the values the sweep's own checkpoint_share gave, now any scope's
+    assert spans.scoped_share(dev, "jit_sweep", "checkpoint") == (
+        pytest.approx(7e-6), pytest.approx(10e-6), 1)
     assert spans.sweep_checkpoint_pct(pt) == pytest.approx(70.0)
     # the device planes agree with xplane.py's reduction of them
     assert tr.busy_s() == pytest.approx(12e-6)
@@ -289,7 +291,8 @@ def test_older_recording_reads_as_before():
     import cut_trace
     tr = xplane.reduce_profile(cut_trace.load(RECORDED_NO_SPANS))
     reg = Registry()
-    view = harness.RunView(None, None, None, None, [], 0, tr, None)
+    view = harness.RunView(None, None, None, None, [], 0, tr, None, None,
+                           None)
     assert reg.metric_reader("decode_step_ms")(view) == pytest.approx(
         NO_SPANS["decode_step_ms"], rel=1e-12)
     assert reg.metric_reader("prefill_ms")(view) == pytest.approx(
@@ -337,3 +340,52 @@ def test_recorded_tpu_spans_pin_the_five_readings():
         ["bench.step_once/ficabu.engine.publish_wait"] * 9)
     # the device reading of xplane.py is the same on this cut
     assert tr.program_seconds()["jit_sweep"][1] == 1
+
+
+def test_scoped_share_reads_as_checkpoint_share_did():
+    """On the recorded TPU cut, ``scoped_share`` of the sweep's
+    ``checkpoint`` scope gives, digit for digit, the (scoped, sweep)
+    seconds the sweep-only ``checkpoint_share`` gave; the decode step holds
+    no such op, and a program with no launch reads None."""
+    pt = spans.reduce_xspace(spans.parse_text(cut_spans.load(RECORDED)))
+    (dev,) = pt.devices
+    assert spans.scoped_share(dev, "jit_sweep", "checkpoint") == (
+        0.08591045382798171, 0.1531678, 1)
+    got = spans.scoped_share(dev, "jit__step", "checkpoint")
+    assert got[0] == 0.0 and got[2] == 23
+    assert spans.scoped_share(dev, "jit_no_such_program", "checkpoint") \
+        is None
+    assert spans.scoped(pt, "jit_sweep", "checkpoint") == \
+        spans.scoped_share(dev, "jit_sweep", "checkpoint")
+
+
+READINGS = ("step_host_ms", "admit_host_ms", "publish_wait_ms",
+            "drain_host_ms", "sweep_checkpoint_pct")
+
+
+def test_the_five_readers_read_the_recorded_tpu_spans():
+    """The five per-layer metrics of the program's spans, scopes and
+    counters, read through a ``RunView`` as the harness builds it, give
+    the values ``test_recorded_tpu_spans_pin_the_five_readings`` pins; an
+    untraced view with no counters reads None in each."""
+    from jax.profiler import ProfileData
+    text = cut_spans.load(RECORDED)
+    pt = spans.reduce_xspace(spans.parse_text(text))
+    tr = xplane.reduce_profile(ProfileData.from_text_proto(text))
+    with open(COUNTERS) as f:
+        counters = json.load(f)
+    reg = Registry()
+    view = harness.RunView(None, None, None, None, [], 0, tr, None, pt,
+                           counters)
+    got = {m: reg.metric_reader(m)(view) for m in READINGS}
+    assert got == {"step_host_ms": pytest.approx(0.8903999999999579),
+                   "admit_host_ms": pytest.approx(134.74878099999998),
+                   "publish_wait_ms": pytest.approx(348.73661192156703),
+                   "drain_host_ms": pytest.approx(498.80905472549085),
+                   "sweep_checkpoint_pct": pytest.approx(56.08910869515768)}
+    assert view.scoped("jit_sweep", "checkpoint") == (
+        0.08591045382798171, 0.1531678, 1)
+    empty = harness.RunView(None, None, None, None, [], 0, None, None, None,
+                            {})
+    assert {m: reg.metric_reader(m)(empty) for m in READINGS} == dict.fromkeys(
+        READINGS)

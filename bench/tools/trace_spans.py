@@ -1,18 +1,19 @@
-"""One traced run of a cell, read with the program's own spans and counters.
+"""One traced run of a cell, with what the harness does not report.
 
     python3 bench/tools/trace_spans.py --workload <cell> --seed <n> \\
         --seconds <s> --out <dir> [--cut <fixture.txtpb.gz> \\
         --cut-start-ms <t> --cut-ms <ms>]
 
-Runs the cell as ``bench/run.py --trace 1`` does, and besides its result
-line reads what the harness does not yet: the engine's and the drain
-worker's counters over the window (``StreamEngine.stats``,
-``ForgetService.stats``), the five readings of ``bench/spans.py``, the
-idle gaps named down to the engine phase, the steps per second of the
-window before and inside the traced span, and how many device ops carry
-the sweep's ``checkpoint`` scope.  Prints them as one JSON object, last line
-on standard output, and keeps the trace under ``<dir>/trace``.  ``--cut``
-also writes a test fixture cut from the trace (``bench/tests/cut_spans.py``).
+Runs the cell as ``bench/run.py --trace 1`` does (its result line carries
+the per-layer metrics, the program's readings among them, and the idle gaps
+named down to the engine phase) and adds, from the harness's own
+``RunView``: the engine's and the drain worker's counters over the window,
+the steps per second of the window before and inside the traced span, how
+many of each ``ficabu.*`` span the trace holds, and how many device ops
+carry the sweep's ``checkpoint`` scope.  Prints them as one JSON object,
+last line on standard output, and keeps the trace under ``<dir>/trace``.
+``--cut`` also writes a test fixture cut from the trace
+(``bench/tests/cut_spans.py``).
 """
 from __future__ import annotations
 
@@ -31,10 +32,6 @@ sys.path.insert(0, BENCH)
 sys.path.insert(0, os.path.join(BENCH, "tests"))
 
 
-def counters(srv):
-    return {"engine": srv.engine.stats(), "drain": srv.svc.stats()}
-
-
 def steps_per_s(win):
     """(steps/s before the traced span, steps/s inside it)."""
     (s0, s1), (t0, t1) = win.trace_steps, win.trace_span
@@ -47,11 +44,11 @@ def op_scopes(pt, n: int = 3):
     """Per device: leaf ops, those with an ``op_name``, those in the
     ``checkpoint`` scope, and a few of each kind's names."""
     import spans
+    pat = spans.scope_re(spans.SCOPE)
     out = []
     for dev in pt.devices:
         named = [o for o in dev.ops if o.op_name]
-        scoped = [o.op_name for o in named
-                  if spans._SCOPE_RE.search(o.op_name)]
+        scoped = [o.op_name for o in named if pat.search(o.op_name)]
         out.append({"leaf_ops": len(dev.ops), "with_op_name": len(named),
                     "scoped": len(scoped),
                     "scoped_examples": sorted(set(scoped))[:n]})
@@ -69,42 +66,23 @@ def main(argv=None) -> int:
     ap.add_argument("--cut-ms", type=float, default=1000.0)
     args = ap.parse_args(argv)
     import harness
-    import loop
-    import spans
-    import xplane
-    got = {"srv": None, "windows": []}
-    inner = loop.serve
-
-    def serve(srv, *a, **kw):
-        c0 = counters(srv)
-        win = inner(srv, *a, **kw)
-        got["windows"].append((win, c0, counters(srv)))
-        return win
-
-    loop.serve = serve
+    got = {}
     tdir = os.path.join(args.out, "trace")
     res = harness.run(args.workload, args.seed, args.seconds, True,
                       t_start=T_START, keep_trace=tdir,
-                      hooks={"server": lambda srv: got.update(srv=srv)})
-    win, c0, c1 = got["windows"][-1]
-    delta = spans.counter_delta(c0, c1)
-    with open(xplane.find_xplane(tdir), "rb") as f:
-        space = spans.parse(f.read())
-    pt = spans.reduce_xspace(space)
-    tr = xplane.load(tdir)
-    out = {"result": res, "counters": delta,
-           "readings": {
-               "step_host_ms": spans.step_host_ms(pt),
-               "admit_host_ms": spans.admit_host_ms(pt),
-               "publish_wait_ms": spans.publish_wait_ms(delta),
-               "drain_host_ms": spans.drain_host_ms(delta),
-               "sweep_checkpoint_pct": spans.sweep_checkpoint_pct(pt)},
-           "idle_gaps": spans.idle_gaps(tr, pt),
-           "steps_per_s_untraced_traced": steps_per_s(win),
+                      hooks={"view": lambda v: got.update(view=v)})
+    view = got["view"]
+    pt = view.program_trace
+    out = {"result": res, "counters": view.counters,
+           "steps_per_s_untraced_traced": steps_per_s(view.window),
            "span_counts": Counter(s.name for s in pt.spans),
            "op_scopes": op_scopes(pt)}
     if args.cut:
         import cut_spans
+        import spans
+        import xplane
+        with open(xplane.find_xplane(tdir), "rb") as f:
+            space = spans.parse(f.read())
         text = cut_spans.cut(space, args.cut_start_ms, args.cut_ms)
         cut_spans.write(args.cut, text)
         out["cut_bytes"] = os.path.getsize(args.cut)

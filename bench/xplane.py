@@ -139,21 +139,30 @@ class Trace:
                 t[1] += n
         return {k: (v[0], v[1]) for k, v in out.items()}
 
-    def idle_gaps(self, n: int = 10) -> List[Tuple[str, float]]:
-        """The ``n`` longest idle gaps of the first device, each named by
-        the host span that overlaps it most (``host.other`` if none)."""
+    def named_gaps(self, n: int = 10) -> List[Tuple[str, float, float]]:
+        """The ``n`` longest idle gaps of the first device, longest first
+        (ties in time order), as (the host span that overlaps it most,
+        ``host.other`` if none; start; end).  Only those ``n`` are named:
+        a traced window holds thousands of gaps between ops."""
         if not self.devices:
             return []
         lo, hi = self.bounds()
+        longest = sorted(gaps(self.devices[0].busy, lo, hi),
+                         key=lambda g: g[0] - g[1])[:n]
         out = []
-        for a, b in gaps(self.devices[0].busy, lo, hi):
+        for a, b in longest:
             best, cover = "host.other", 0.0
             for name, s, e in self.host_spans:
                 ov = min(b, e) - max(a, s)
                 if ov > cover:
                     best, cover = name, ov
-            out.append((best, b - a))
-        return sorted(out, key=lambda kv: -kv[1])[:n]
+            out.append((best, a, b))
+        return out
+
+    def idle_gaps(self, n: int = 10) -> List[Tuple[str, float]]:
+        """The ``n`` longest idle gaps of the first device, each named by
+        the host span that overlaps it most (``host.other`` if none)."""
+        return [(k, b - a) for k, a, b in self.named_gaps(n)]
 
 
 def reduce_profile(pd, host_prefix: str = "bench.") -> Trace:
