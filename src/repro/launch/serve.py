@@ -101,6 +101,7 @@ from repro.api.facade import CACHE_ENV
 from repro.data import LMDataConfig, make_lm_domains
 from repro.fleet import Fleet, FleetSpec, TenantSpec
 from repro.models import lm as LM
+from repro.models.layers import Q_CHUNK
 from repro.obs import telemetry as _t
 
 
@@ -368,6 +369,20 @@ def engine_fingerprint(events) -> str:
     return _t.fingerprint(evs)
 
 
+def admission_prefill_block(prompt_len: int) -> int:
+    """Tokens per prefill launch for an admission of prompts ``prompt_len``
+    tokens long: the whole prompt, one launch, when it is at most
+    ``layers.Q_CHUNK`` — the model's bound on materialised score rows (a
+    wide block holds a [width, H, block, S_max] f32 score block) — else
+    the largest divisor of the prompt not above it, so every launch of an
+    admission has one signature.  Where an attention cache can wrap or a
+    block is recurrent, ``LM.prefill`` scans decode steps over the block
+    instead; the block then only sets the launch count, and the same rule
+    holds."""
+    P = int(prompt_len)
+    return max(d for d in range(1, min(P, Q_CHUNK) + 1) if P % d == 0)
+
+
 class StreamEngine:
     """Continuous-batching decode engine with zero-downtime drains.
 
@@ -383,8 +398,9 @@ class StreamEngine:
          single worker thread against the tenant's SHADOW tree
          (``ForgetService.run_shadow``) — serving never stalls for it;
       3. admits pending sequences into free slots via a fixed-width
-         chunked prefill (``models.lm.prefill``) scattered into the pool
-         caches (``models.lm.scatter_cache_rows``);
+         prefill (``models.lm.prefill``; one launch for a prompt of up to
+         ``layers.Q_CHUNK`` tokens, see ``admission_prefill_block``)
+         scattered into the pool caches (``models.lm.scatter_cache_rows``);
       4. evicts finished sequences (host-side length bookkeeping — no
          device sync) and starts an async device->host copy of their
          output row;
@@ -406,13 +422,14 @@ class StreamEngine:
     ``engine.evict`` / ``engine.decode`` inside it, and ``drain`` around
     each sweep on the worker thread, linked to its ``engine.fire`` and
     ``engine.publish_wait`` by ``fire_step`` and ``group`` (the group's
-    position among that step's due groups).  ``stats()`` returns the
-    engine's plain counters.
+    position among that step's due groups); ``engine.admit`` carries the
+    admission's prefill ``launches``.  ``stats()`` returns the engine's
+    plain counters.
     """
 
     def __init__(self, params, cfg, *, gen_len: int, prompt_len: int,
                  max_batch: int = 8, admit_chunk: int = 4,
-                 prefill_block: int = 8, publish_lag: int = 16,
+                 publish_lag: int = 16,
                  service: Optional[ForgetService] = None):
         if gen_len < 1 or prompt_len < 1:
             raise ValueError(f"StreamEngine needs gen_len/prompt_len >= 1, "
@@ -423,7 +440,7 @@ class StreamEngine:
         self.P = int(prompt_len)
         self.B = int(max_batch)
         self.admit_chunk = min(int(admit_chunk), self.B)
-        self.prefill_block = prefill_block
+        self.prefill_block = admission_prefill_block(self.P)
         self.publish_lag = int(publish_lag)
         self.svc = service
         if service is not None:
@@ -448,6 +465,7 @@ class StreamEngine:
         self.admissions = 0
         self.admitted_rows = 0
         self.padded_rows = 0
+        self.prefill_launches = 0
         # publication deadlines that found the drain unfinished, and the
         # seconds the engine thread then spent blocked joining it
         self.publish_waits = 0
@@ -502,8 +520,9 @@ class StreamEngine:
             rows, free = free[:take], free[take:]
             width = self.admit_chunk
             seqs = [sid for sid, _ in chunk]
+            launches = self.P // self.prefill_block
             with _t.span("engine.admit", seqs=seqs, width=width,
-                         padded=width - take):
+                         padded=width - take, launches=launches):
                 # fixed-width sub-batch: ONE prefill/admit program
                 # signature.  Padding rows repeat the last prompt and
                 # scatter to row index B — out of bounds, dropped by the
@@ -529,6 +548,7 @@ class StreamEngine:
             self.admissions += 1
             self.admitted_rows += take
             self.padded_rows += width - take
+            self.prefill_launches += launches
             _t.emit("batch.admit", step=self.step, rows=rows,
                     seqs=seqs, width=width, padded=width - take)
 
@@ -650,12 +670,13 @@ class StreamEngine:
 
     def stats(self) -> Dict[str, float]:
         """The engine's counters: steps, admissions and their rows (padding
-        rows apart), publications, aborts, and the publication deadlines
-        that blocked on an unfinished drain with the seconds they
-        blocked."""
+        rows apart) and prefill launches, publications, aborts, and the
+        publication deadlines that blocked on an unfinished drain with the
+        seconds they blocked."""
         return {"steps": self.step, "admissions": self.admissions,
                 "admitted_rows": self.admitted_rows,
                 "padded_rows": self.padded_rows,
+                "prefill_launches": self.prefill_launches,
                 "publications": self.publications, "aborts": self.aborts,
                 "publish_waits": self.publish_waits,
                 "publish_wait_s": self.publish_wait_s}
@@ -1049,7 +1070,6 @@ def _main_stream(args, cfg, params, tokens, domains, seq_len: int,
                        prompt_len=args.prompt_len,
                        max_batch=serve.max_batch,
                        admit_chunk=serve.admit_chunk,
-                       prefill_block=args.prefill_block,
                        publish_lag=serve.publish_lag,
                        service=svc)
     # the burst schedule lives on the ENGINE-STEP clock in stream mode:
@@ -1169,7 +1189,10 @@ def main(argv=None) -> dict:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen-len", type=int, default=8)
     ap.add_argument("--prefill-block", type=int, default=8,
-                    help="chunked-prefill block size (tokens per dispatch)")
+                    help="chunked-prefill block size (tokens per dispatch) "
+                         "of the generate loops (--serve-mode batch, "
+                         "--fleet); the stream engine picks its own from "
+                         "--prompt-len")
     ap.add_argument("--serve-mode", choices=("batch", "stream"),
                     default="batch",
                     help="'batch': the legacy fixed-batch generate loop "

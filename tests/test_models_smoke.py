@@ -185,6 +185,18 @@ def test_param_count_kimi_is_about_1t():
 # ---------------------------------------------------------------------------
 # Chunked prefill: bit-exact vs the token-by-token decode walk
 # ---------------------------------------------------------------------------
+def _greedy_from(params, cfg, logits, cache, P, G):
+    """G greedy tokens decoded from a prefill's last logits and cache."""
+    decode_jit = jax.jit(lambda p, c, t, pos: LM.decode_step(p, cfg, t, c, pos))
+    tok = jnp.argmax(logits[:, -1:], axis=-1)
+    out = []
+    for j in range(G):
+        out.append(np.asarray(tok)[:, 0])
+        logits, cache = decode_jit(params, cache, tok, jnp.int32(P + j))
+        tok = jnp.argmax(logits[:, -1:], axis=-1)
+    return np.stack(out, axis=1)
+
+
 def _tokenwise_prefill(params, cfg, toks, S_max):
     decode_jit = jax.jit(lambda p, c, t, pos: LM.decode_step(p, cfg, t, c, pos))
     cache = LM.init_cache(cfg, toks.shape[0], S_max)
@@ -200,6 +212,8 @@ def _tokenwise_prefill(params, cfg, toks, S_max):
     ("gemma3-1b", 20, 7),        # P > window 16: ring wrap -> scan mode
     ("recurrentgemma-9b", 12, 4),  # RG-LRU + local hybrid
     ("xlstm-125m", 12, 6),       # mLSTM/sLSTM states
+    ("internvl2-1b", 8, 8),      # whole prompt, one wide block (qwen2-style)
+    ("yi-6b", 8, 8),             # whole prompt, one wide block (llama-style)
 ])
 def test_chunked_prefill_bit_exact(arch_id, P, block, key):
     """LM.prefill consumes the prompt in blocks yet must reproduce the
@@ -227,6 +241,33 @@ def test_chunked_prefill_bit_exact(arch_id, P, block, key):
     np.testing.assert_array_equal(np.asarray(last), ref_logits[:, -1:])
 
 
+@pytest.mark.parametrize("arch_id", ["internvl2-1b", "yi-6b"])
+def test_whole_prompt_prefill_matches_tokenwise(arch_id, key):
+    """The stream engine's admission shape: the whole prompt in ONE wide
+    block.  Off the CPU's small-dot range (query-group rows G*C above 16
+    there, here 2*24) XLA:CPU contracts the attention einsums with another
+    kernel than the decode step's, so logits and caches agree to f32
+    rounding, not bit for bit; the greedy continuation agrees exactly."""
+    cfg = configs.get(arch_id).smoke
+    params = LM.init_lm(key, cfg)
+    B, P, G = 2, 24, 6
+    toks = jax.random.randint(jax.random.PRNGKey(3), (B, P), 0, cfg.vocab)
+    ref_logits, ref_cache = _tokenwise_prefill(params, cfg, toks, P + G)
+    cache = LM.init_cache(cfg, B, P + G)
+    assert P <= LM._min_attn_cache(cfg, cache)
+    logits, cache = LM.prefill(params, cfg, toks, cache, block=P,
+                               last_only=False)
+    np.testing.assert_allclose(np.asarray(logits), ref_logits,
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(cache),
+                    jax.tree_util.tree_leaves(ref_cache)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(
+        _greedy_from(params, cfg, logits, cache, P, G),
+        _greedy_from(params, cfg, jnp.asarray(ref_logits), ref_cache, P, G))
+
+
 def test_chunked_prefill_then_decode_matches(key):
     """Greedy decode from a chunked prefill continues identically to one
     from the token-by-token prefill (the serving handoff point)."""
@@ -234,20 +275,10 @@ def test_chunked_prefill_then_decode_matches(key):
     params = LM.init_lm(key, cfg)
     B, P, G = 2, 10, 6
     toks = jax.random.randint(jax.random.PRNGKey(5), (B, P), 0, cfg.vocab)
-    decode_jit = jax.jit(lambda p, c, t, pos: LM.decode_step(p, cfg, t, c, pos))
-
-    def continue_from(logits, cache):
-        tok = jnp.argmax(logits[:, -1:], axis=-1)
-        out = []
-        for j in range(G):
-            out.append(np.asarray(tok)[:, 0])
-            logits, cache = decode_jit(params, cache, tok, jnp.int32(P + j))
-            tok = jnp.argmax(logits[:, -1:], axis=-1)
-        return np.stack(out, axis=1)
-
     lg_ref, cache_ref = _tokenwise_prefill(params, cfg, toks, P + G)
-    gen_ref = continue_from(jnp.asarray(lg_ref[:, -1:]), cache_ref)
+    gen_ref = _greedy_from(params, cfg, jnp.asarray(lg_ref[:, -1:]),
+                           cache_ref, P, G)
     cache = LM.init_cache(cfg, B, P + G)
     lg, cache = LM.prefill(params, cfg, toks, cache, block=4)
-    gen = continue_from(lg, cache)
+    gen = _greedy_from(params, cfg, lg, cache, P, G)
     np.testing.assert_array_equal(gen, gen_ref)

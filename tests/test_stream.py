@@ -16,18 +16,23 @@
     ``queue.age_skew`` event, ``submit(now=-1)`` raises, and
     ``pending_entries`` is the public queue view (folded entries expand);
   * the stream engine's outputs match the legacy batched ``generate``
-    loop, and staggered-admission runs are deterministic.
+    loop, and staggered-admission runs are deterministic;
+  * admission prefills a prompt of up to ``layers.Q_CHUNK`` tokens in ONE
+    launch (longer ones in equal divisor blocks), wide or scanned, with
+    the greedy outputs of the token-wise decode walk.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import configs
 from repro.api import ServeSpec
 from repro.data import synthetic as syn
 from repro.fleet import DrainScheduler
 from repro.launch.serve import (ForgetService, StreamEngine,
-                                _trees_bitwise_equal, engine_fingerprint,
+                                _trees_bitwise_equal,
+                                admission_prefill_block, engine_fingerprint,
                                 generate)
 from repro.models import lm as LM
 from repro.obs import telemetry as _t
@@ -264,3 +269,62 @@ def test_staggered_stream_deterministic(cfg, data):
     # events shift engine seq values at scheduler-dependent points
     fp = [engine_fingerprint(ev) for ev in (ev_a, ev_b)]
     assert fp[0] == fp[1]
+
+
+# -- admission: one prefill launch per admission ------------------------------
+
+@pytest.mark.parametrize("arch_id,Plen,S_max,block,wide", [
+    ("internvl2-1b", 512, 640, 512, True),   # the cell: one wide block
+    ("internvl2-1b", 1000, 1128, 500, True),  # past Q_CHUNK: equal divisors
+    ("internvl2-1b", 1536, 1664, 512, True),
+    ("gemma3-1b", 24, 30, 24, False),       # window 16 < P: the scan path
+])
+def test_admission_prefill_block(arch_id, Plen, S_max, block, wide):
+    cfg = configs.get(arch_id).smoke
+    assert admission_prefill_block(Plen) == block
+    assert Plen % block == 0
+    sub = jax.eval_shape(lambda: LM.init_cache(cfg, 4, S_max))
+    assert (Plen <= LM._min_attn_cache(cfg, sub)) == wide
+
+
+def _tokenwise_greedy(params, cfg, prompts, gen_len):
+    """Greedy continuation after feeding the prompt one decode step per
+    token — the walk every prefill mode must reproduce."""
+    B, Plen = prompts.shape
+    dj = _decode_jit(cfg)
+    cache = LM.init_cache(cfg, B, Plen + gen_len)
+    for i in range(Plen):
+        logits, cache = dj(params, cache, prompts[:, i:i + 1], jnp.int32(i))
+    out = []
+    tok = jnp.argmax(logits[:, -1:], axis=-1)
+    for j in range(gen_len):
+        out.append(np.asarray(tok)[:, 0])
+        logits, cache = dj(params, cache, tok, jnp.int32(Plen + j))
+        tok = jnp.argmax(logits[:, -1:], axis=-1)
+    return np.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("arch_id", [None, "gemma3-1b"])
+def test_stream_admits_in_one_launch(cfg, arch_id):
+    """A 24-token prompt is admitted in ONE prefill launch — wide on the
+    dense tiny config, a scan of decode steps where gemma3's 16-slot
+    window cache wraps — and every sequence decodes what the token-wise
+    walk decodes."""
+    if arch_id is not None:
+        cfg = configs.get(arch_id).smoke
+    Plen, n_seq = 24, 5
+    params = LM.init_lm(jax.random.PRNGKey(1), cfg)
+    prompts = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(2), (n_seq, Plen), 0, cfg.vocab))
+    eng = StreamEngine(params, cfg, gen_len=G, prompt_len=Plen,
+                       max_batch=4, admit_chunk=2)
+    assert eng.prefill_block == Plen
+    for i in range(n_seq):
+        eng.enqueue(i, prompts[i])
+    got = eng.run()
+    st = eng.stats()
+    assert st["admissions"] == 3
+    assert st["prefill_launches"] == st["admissions"]
+    want = _tokenwise_greedy(params, cfg, jnp.asarray(prompts), G)
+    for i in range(n_seq):
+        np.testing.assert_array_equal(got[i], want[i])
